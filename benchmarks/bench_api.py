@@ -23,6 +23,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Problem, Solver
 from repro.graph.edgelist import EdgeList
 from repro.graph.generators import chung_lu_power_law
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--out", default=os.path.join("experiments", "bench", "BENCH_api.json"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     edges = chung_lu_power_law(args.n, exponent=2.0, avg_deg=args.avg_deg, seed=0)
     perm = np.random.default_rng(1).permutation(edges.src.shape[0])

@@ -15,6 +15,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.countsketch import SketchBackend, make_sketch_params
 from repro.core.engine import ExactBackend, UndirectedThreshold, run_peel
 from repro.graph.generators import chung_lu_power_law
@@ -41,6 +42,7 @@ def main(argv=None) -> int:
     ap.add_argument("--sketch-b", type=int, default=1 << 15)
     ap.add_argument("--tile-size", type=int, default=2048)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     edges = chung_lu_power_law(args.n, exponent=2.0, avg_deg=args.avg_deg, seed=0)
     m = int(edges.num_real_edges())

@@ -34,6 +34,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Problem, Solver
 from repro.graph.generators import chung_lu_power_law
 
@@ -63,6 +64,7 @@ def main(argv=None) -> int:
         "--out", default=os.path.join("experiments", "bench", "BENCH_peel.json")
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     edges = chung_lu_power_law(
         args.n, exponent=args.exponent, avg_deg=args.avg_deg, seed=0

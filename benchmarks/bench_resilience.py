@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro import faults
 from repro.core import Problem, Solver
 from repro.faults import FaultPlan
@@ -83,6 +84,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=os.path.join(
         "experiments", "bench", "BENCH_resilience.json"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     edges = chung_lu_power_law(
         args.n, exponent=2.0, avg_deg=args.avg_deg, seed=0

@@ -40,14 +40,13 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Problem, Solver
 from repro.graph.generators import chung_lu_power_law
 from repro.serve.densest import DensestQueryEngine
@@ -62,6 +61,10 @@ from repro.graph.generators import chung_lu_power_law
 from repro.serve.densest import DensestQueryEngine
 
 cfg = json.loads({cfg!r})
+# Program acquisition is what this child measures: JAX's own persistent
+# compilation cache stays off, so "uncached" really compiles.
+import jax
+jax.config.update("jax_enable_compilation_cache", False)
 edges = chung_lu_power_law(cfg["n"], exponent=2.0, avg_deg=cfg["avg_deg"], seed=0)
 prob = Problem.undirected(eps=cfg["eps"], max_passes=cfg["max_passes"],
                           compaction="off")
@@ -130,9 +133,11 @@ def main(argv=None) -> int:
     ap.add_argument("--eps", type=float, default=0.5)
     ap.add_argument("--max-passes", type=int, default=32)
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--cache-dir", default=None,
-                    help="disk cache for the cold-start protocol "
-                         "(default: a fresh temp dir)")
+    ap.add_argument("--cache-dir", default=os.path.join(
+                        "experiments", "serve_cache"),
+                    help="Solver disk cache for the cold-start protocol "
+                         "(a fixed path: a second run's populate child "
+                         "finds the first run's entries)")
     ap.add_argument("--skip-cold-start", action="store_true",
                     help="skip the subprocess cold-start measurements")
     ap.add_argument("--skip-sweep", action="store_true",
@@ -147,6 +152,44 @@ def main(argv=None) -> int:
         "experiments", "bench", "BENCH_serve.json"))
     args = ap.parse_args(argv)
 
+    seeds = np.random.default_rng(7).integers(0, args.n, args.queries).tolist()
+
+    # ---- cold start: fresh subprocess, uncached vs warm disk cache ------
+    # Runs FIRST, before this process touches jax: each child must be the
+    # only process holding the accelerator while it runs.
+    cold_start = None
+    if not args.skip_cold_start:
+        base = {
+            "n": args.n, "avg_deg": args.avg_deg, "eps": args.eps,
+            "max_passes": args.max_passes, "radius": args.radius,
+            "max_ego_nodes": args.max_ego_nodes, "seed": seeds[0],
+        }
+        cold = _run_child(dict(base, cache_dir=None, expect_warm=False))
+        t0 = time.perf_counter()
+        populate = _run_child(
+            dict(base, cache_dir=args.cache_dir, expect_warm=False)
+        )
+        populate_wall = time.perf_counter() - t0
+        warm = _run_child(
+            dict(base, cache_dir=args.cache_dir, expect_warm=True)
+        )
+        assert warm["density"] == cold["density"], "cold/warm mismatch"
+        cold_start = {
+            "uncached_first_query_s": round(cold["first_query_s"], 4),
+            "uncached_programs_compiled": cold["trace_count"],
+            "populate_first_query_s": round(populate["first_query_s"], 4),
+            "populate_child_wall_s": round(populate_wall, 4),
+            "warm_disk_first_query_s": round(warm["first_query_s"], 4),
+            "warm_disk_programs_compiled": warm["trace_count"],
+            "warm_disk_hits": warm["disk_hits"],
+            "cold_start_speedup_x": round(
+                cold["first_query_s"] / max(warm["first_query_s"], 1e-9), 1
+            ),
+        }
+        print("cold_start:", cold_start)
+
+    enable_compile_cache()
+
     edges = chung_lu_power_law(
         args.n, exponent=2.0, avg_deg=args.avg_deg, seed=0
     )
@@ -155,8 +198,6 @@ def main(argv=None) -> int:
     prob = Problem.undirected(
         eps=args.eps, max_passes=args.max_passes, compaction="off"
     )
-    seeds = np.random.default_rng(7).integers(0, args.n, args.queries).tolist()
-
     def fresh_engine(**kw):
         return DensestQueryEngine(
             edges, prob, radius=args.radius, max_batch=args.max_batch,
@@ -175,6 +216,8 @@ def main(argv=None) -> int:
             "max_passes": args.max_passes,
         }
     }
+    if cold_start is not None:
+        report["cold_start"] = cold_start
 
     # ---- batched engine (the serving path) ------------------------------
     eng = fresh_engine()
@@ -268,48 +311,6 @@ def main(argv=None) -> int:
     ab = report["batched"]["qps"] / report["sequential_bucketed"]["qps"]
     report["batched_vs_bucketed_qps_x"] = round(ab, 2)
     print(f"batched vs sequential(bucketed, warm) qps: {ab:.2f}x")
-
-    # ---- cold start: fresh subprocess, uncached vs warm disk cache ------
-    if not args.skip_cold_start:
-        cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="bench_serve_")
-        owns_dir = args.cache_dir is None
-        try:
-            base = {
-                "n": args.n, "avg_deg": args.avg_deg, "eps": args.eps,
-                "max_passes": args.max_passes, "radius": args.radius,
-                "max_ego_nodes": args.max_ego_nodes, "seed": seeds[0],
-            }
-            cold = _run_child(
-                dict(base, cache_dir=None, expect_warm=False)
-            )
-            t0 = time.perf_counter()
-            populate = _run_child(
-                dict(base, cache_dir=cache_dir, expect_warm=False)
-            )
-            populate_wall = time.perf_counter() - t0
-            warm = _run_child(
-                dict(base, cache_dir=cache_dir, expect_warm=True)
-            )
-            assert warm["density"] == cold["density"], "cold/warm mismatch"
-            report["cold_start"] = {
-                "uncached_first_query_s": round(cold["first_query_s"], 4),
-                "uncached_programs_compiled": cold["trace_count"],
-                "populate_first_query_s": round(
-                    populate["first_query_s"], 4
-                ),
-                "populate_child_wall_s": round(populate_wall, 4),
-                "warm_disk_first_query_s": round(warm["first_query_s"], 4),
-                "warm_disk_programs_compiled": warm["trace_count"],
-                "warm_disk_hits": warm["disk_hits"],
-                "cold_start_speedup_x": round(
-                    cold["first_query_s"] / max(warm["first_query_s"], 1e-9),
-                    1,
-                ),
-            }
-            print("cold_start:", report["cold_start"])
-        finally:
-            if owns_dir:
-                shutil.rmtree(cache_dir, ignore_errors=True)
 
     # ---- local-vs-BFS extraction scaling sweep --------------------------
     # THE substrate='local' claim (ISSUE 10): per-query work of the

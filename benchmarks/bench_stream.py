@@ -35,6 +35,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.streaming import StreamingDensest, chunked_from_memmap
 from repro.graph.edgelist import save_edges_memmap
 from repro.graph.generators import chung_lu_power_law
@@ -69,6 +70,7 @@ def main(argv=None) -> int:
         "--out", default=os.path.join("experiments", "bench", "BENCH_stream.json")
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     edges = chung_lu_power_law(
         args.n, exponent=args.exponent, avg_deg=args.avg_deg, seed=0

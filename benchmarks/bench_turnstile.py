@@ -38,6 +38,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Problem, Solver
 from repro.core.turnstile import TurnstileDensest
 from repro.graph.edgelist import apply_updates, from_numpy
@@ -69,6 +70,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=os.path.join(
         "experiments", "bench", "BENCH_turnstile.json"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     envelope = (1 + args.eps) * (2 + 2 * args.eps)
     prob_exact = Problem.undirected(eps=args.eps, compaction="off")

@@ -206,34 +206,12 @@ def table4(t: int = 5) -> List[Dict[str, Any]]:
 
 
 def fig67() -> List[Dict[str, Any]]:
-    """Per-pass wall time of the edge-sharded shard_map peel on the host
-    mesh, for growing graph sizes (the Hadoop plot's shape, CPU scale).
+    """Per-pass wall time of the edge-sharded shard_map peel over every
+    visible device, for growing graph sizes (the Hadoop plot's shape).
 
-    If jax is still single-device, re-executes itself in a subprocess with 8
-    forced host devices so the collectives are real."""
-    import json as _json
-    import os as _os
-    import subprocess
-    import sys as _sys
-
-    if jax.device_count() == 1 and not _os.environ.get("_FIG67_CHILD"):
-        env = dict(_os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        env["_FIG67_CHILD"] = "1"
-        env.setdefault("PYTHONPATH", "src")
-        code = (
-            "import json; from benchmarks.paper_benches import fig67; "
-            "print('FIG67='+json.dumps(fig67()))"
-        )
-        out = subprocess.run(
-            [_sys.executable, "-c", code], env=env, capture_output=True,
-            text=True, timeout=1200,
-        )
-        for line in out.stdout.splitlines():
-            if line.startswith("FIG67="):
-                return _json.loads(line[len("FIG67="):])
-        raise RuntimeError(f"fig67 child failed: {out.stderr[-2000:]}")
-
+    On a CPU host, give the process several devices yourself
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) so the
+    collectives are real; one device runs the same program unsharded."""
     from jax.sharding import Mesh
 
     from repro.core.mapreduce import densest_subgraph_distributed

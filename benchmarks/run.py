@@ -14,6 +14,9 @@ import time
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
     from benchmarks.paper_benches import ALL, _rows_to_csv
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     names = [a for a in argv if not a.startswith("-")] or list(ALL)
     out_dir = os.path.join("experiments", "bench")

@@ -1210,9 +1210,8 @@ class Solver:
         and stops at ``compact_below``; the alive-edge trigger count is
         psummed (``MeshSegmentSumBackend.count_edges``) so all devices
         agree on the segment boundary."""
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map
 
         axes = tuple(problem.edge_axes)
         if problem.backend == "sketch":
@@ -1347,9 +1346,9 @@ class Solver:
         absolute pass counter after each rung (the ladder report's
         per-rung passes, fetched with the result in the same launch).
         """
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.core.mapreduce import mesh_compact_edges
 
         axes = tuple(problem.edge_axes)

@@ -35,9 +35,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.api import DenseSubgraphResult, Problem, default_solver, solve
 from repro.core.density import max_passes_bound
 from repro.core.engine import (

@@ -51,19 +51,44 @@ def planted_dense_subgraph(
 
 
 def chung_lu_power_law(
-    n: int, exponent: float = 2.2, avg_deg: float = 8.0, seed: int = 0
+    n: int,
+    exponent: float = 2.2,
+    avg_deg: float = 8.0,
+    seed: int = 0,
+    n_edges: int | None = None,
 ) -> EdgeList:
     """Chung-Lu graph with power-law expected degrees (heavy-tail, like the
-    paper's social graphs)."""
+    paper's social graphs).
+
+    ``n_edges`` asks for exactly that many distinct edges (a published
+    graph's n and m, e.g. the Table 1 shapes): endpoint pairs are drawn
+    until at least ``n_edges`` survive deduplication, then a uniform subset
+    of that size is kept, so the degree law is the same as the
+    ``avg_deg`` spelling's.  ``avg_deg`` is ignored then."""
     rng = np.random.default_rng(seed)
     w = (np.arange(1, n + 1) ** (-1.0 / (exponent - 1.0))).astype(np.float64)
     w *= n * avg_deg / w.sum()
     p = w / w.sum()
-    m = int(n * avg_deg / 2)
-    src = rng.choice(n, size=m, p=p)
-    dst = rng.choice(n, size=m, p=p)
-    src, dst = dedup_edges(src, dst, directed=False)
-    return from_numpy(src, dst, n)
+    if n_edges is None:
+        m = int(n * avg_deg / 2)
+        src = rng.choice(n, size=m, p=p)
+        dst = rng.choice(n, size=m, p=p)
+        src, dst = dedup_edges(src, dst, directed=False)
+        return from_numpy(src, dst, n)
+    target = int(n_edges)
+    src = np.zeros(0, np.int64)
+    dst = np.zeros(0, np.int64)
+    draw = target
+    while True:
+        src = np.concatenate([src, rng.choice(n, size=draw, p=p)])
+        dst = np.concatenate([dst, rng.choice(n, size=draw, p=p)])
+        s, d = dedup_edges(src, dst, directed=False)
+        if len(s) >= target:
+            break
+        # Top up by the shortfall over the observed keep rate, plus margin.
+        draw = int((target - len(s)) * len(src) / max(len(s), 1) * 1.1) + 1
+    keep = np.sort(rng.choice(len(s), size=target, replace=False))
+    return from_numpy(s[keep], d[keep], n)
 
 
 def barabasi_albert(n: int, m_attach: int = 4, seed: int = 0) -> EdgeList:

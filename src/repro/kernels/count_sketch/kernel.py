@@ -25,11 +25,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import hashing
 
 
 def _cs_kernel(x_ref, w_ref, ah_ref, ch_ref, ag_ref, cg_ref, out_ref, *, n_buckets, col_chunk):
+    i = pl.program_id(0)
     eb = pl.program_id(1)
 
     @pl.when(eb == 0)
@@ -38,10 +40,10 @@ def _cs_kernel(x_ref, w_ref, ah_ref, ch_ref, ag_ref, cg_ref, out_ref, *, n_bucke
 
     x = x_ref[0, :].astype(jnp.uint32)
     w = w_ref[0, :]
-    a_h = ah_ref[0]
-    c_h = ch_ref[0]
-    a_g = ag_ref[0]
-    c_g = cg_ref[0]
+    a_h = ah_ref[i]
+    c_h = ch_ref[i]
+    a_g = ag_ref[i]
+    c_g = cg_ref[i]
 
     # Shared multiply-shift family (plain uint32 jnp ops, traceable here).
     bucket = hashing.bucket32(hashing.mix32(a_h, c_h, x), n_buckets)
@@ -94,17 +96,17 @@ def count_sketch_update_pallas(
     w2 = w.reshape(1, e)
 
     kern = functools.partial(_cs_kernel, n_buckets=n_buckets, col_chunk=col_chunk)
+    # Per-table hash scalars live whole in SMEM, indexed by the grid row (the
+    # TPU lowering refuses rank-1 (1,)-blocks).
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kern,
         grid=(t, n_eb),
         in_specs=[
             pl.BlockSpec((1, block_e), lambda i, e_: (0, e_)),
             pl.BlockSpec((1, block_e), lambda i, e_: (0, e_)),
-            pl.BlockSpec((1,), lambda i, e_: (i,)),
-            pl.BlockSpec((1,), lambda i, e_: (i,)),
-            pl.BlockSpec((1,), lambda i, e_: (i,)),
-            pl.BlockSpec((1,), lambda i, e_: (i,)),
-        ],
+        ]
+        + [smem] * 4,
         out_specs=pl.BlockSpec((1, 8, n_buckets), lambda i, e_: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, 8, n_buckets), jnp.float32),
         interpret=interpret,

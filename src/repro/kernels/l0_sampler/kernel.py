@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import hashing
 from repro.kernels.l0_sampler.ops import level_from_hash
@@ -56,6 +57,7 @@ def _l0_kernel(
     block_c,
     col_chunk,
 ):
+    j = pl.program_id(0)
     cb = pl.program_id(1)
     eb = pl.program_id(2)
 
@@ -76,7 +78,7 @@ def _l0_kernel(
     fp = hashing.mix32_pair(af_ref[0], af_ref[1], cf_ref[0], uu, vv)
     fp_i = jax.lax.bitcast_convert_type(fp, jnp.int32)
     cell = hashing.bucket32(
-        hashing.mix32_pair(ac_ref[0, 0], ac_ref[0, 1], cc_ref[0], uu, vv), n_cells
+        hashing.mix32_pair(ac_ref[j, 0], ac_ref[j, 1], cc_ref[j], uu, vv), n_cells
     )
 
     # Flattened (level, cell) column, local to this column block.
@@ -146,6 +148,10 @@ def l0_delta_pallas(
         block_c=block_c,
         col_chunk=col_chunk,
     )
+    # The hash parameters are scalars: whole arrays in SMEM, indexed by the
+    # table's grid row inside the kernel (the TPU lowering refuses rank-1
+    # (1,)-blocks and a (1, 2) block of the (d, 2) multiplier array).
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kern,
         grid=(d, n_cb, n_eb),
@@ -153,13 +159,8 @@ def l0_delta_pallas(
             pl.BlockSpec((1, block_e), lambda j, c_, e_: (0, e_)),
             pl.BlockSpec((1, block_e), lambda j, c_, e_: (0, e_)),
             pl.BlockSpec((1, block_e), lambda j, c_, e_: (0, e_)),
-            pl.BlockSpec((2,), lambda j, c_, e_: (0,)),
-            pl.BlockSpec((1,), lambda j, c_, e_: (0,)),
-            pl.BlockSpec((2,), lambda j, c_, e_: (0,)),
-            pl.BlockSpec((1,), lambda j, c_, e_: (0,)),
-            pl.BlockSpec((1, 2), lambda j, c_, e_: (j, 0)),
-            pl.BlockSpec((1,), lambda j, c_, e_: (j,)),
-        ],
+        ]
+        + [smem] * 6,
         out_specs=pl.BlockSpec((1, 8, block_c), lambda j, c_, e_: (j, 0, c_)),
         out_shape=jax.ShapeDtypeStruct((d, 8, n_cols), jnp.int32),
         interpret=interpret,

@@ -30,8 +30,8 @@ from jax.experimental import pallas as pl
 def _degree_kernel(tl_ref, w_ref, out_ref):
     """One (tile, edge-block) grid step.
 
-    tl_ref:  int32[1, E_blk]      target ids local to this tile (-1 = padding)
-    w_ref:   float32[1, E_blk]    current alive-weight of each slot (0 = dead)
+    tl_ref:  int32[1, 1, E_blk]   target ids local to this tile (-1 = padding)
+    w_ref:   float32[1, 1, E_blk] current alive-weight of each slot (0 = dead)
     out_ref: float32[1, 8, T]     this tile's degree row (8 sublanes for MXU)
     """
     eb = pl.program_id(1)
@@ -40,8 +40,8 @@ def _degree_kernel(tl_ref, w_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tl = tl_ref[0, :]
-    w = w_ref[0, :]
+    tl = tl_ref[0, 0, :]
+    w = w_ref[0, 0, :]
     t = out_ref.shape[2]
     # one-hot via iota compare; padding (-1) matches no column.
     cols = jax.lax.broadcasted_iota(jnp.int32, (tl.shape[0], t), 1)
@@ -72,15 +72,18 @@ def tiled_degrees_pallas(
     assert max_epT % block_e == 0, (max_epT, block_e)
     n_eb = max_epT // block_e
 
+    # A unit middle axis keeps each block's last two dims at (1, block_e):
+    # the TPU lowering needs them equal to the array's or (8, 128)-aligned,
+    # and a (1, block_e) block of the 2-D array is neither.
     out = pl.pallas_call(
         _degree_kernel,
         grid=(n_tiles, n_eb),
         in_specs=[
-            pl.BlockSpec((1, block_e), lambda t, e: (t, e)),
-            pl.BlockSpec((1, block_e), lambda t, e: (t, e)),
+            pl.BlockSpec((1, 1, block_e), lambda t, e: (t, 0, e)),
+            pl.BlockSpec((1, 1, block_e), lambda t, e: (t, 0, e)),
         ],
         out_specs=pl.BlockSpec((1, 8, tile_size), lambda t, e: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 8, tile_size), jnp.float32),
         interpret=interpret,
-    )(target_local, w)
+    )(target_local[:, None, :], w[:, None, :])
     return out[:, 0, :]

@@ -72,6 +72,18 @@ def test_pass_bound_lemma4():
         assert int(res.passes) <= max_passes_bound(3000, eps)
 
 
+@pytest.mark.parametrize("n,m", [(2000, 9000), (500, 4000)])
+def test_chung_lu_exact_edge_count(n, m):
+    """``n_edges`` gives exactly m distinct loop-free undirected edges (the
+    Table 1 shapes name n and m, not an average degree)."""
+    edges = chung_lu_power_law(n, seed=3, n_edges=m)
+    src = np.asarray(edges.src).astype(np.int64)
+    dst = np.asarray(edges.dst).astype(np.int64)
+    assert len(src) == m and edges.n_nodes == n
+    assert np.all(src < dst)
+    assert len(np.unique(src * n + dst)) == m
+
+
 def test_planted_dense_block_recovered():
     edges, planted = planted_dense_subgraph(500, avg_deg=4, k=30, p_dense=0.8, seed=3)
     res = densest_subgraph(edges, eps=0.25)

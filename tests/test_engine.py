@@ -174,9 +174,9 @@ def _run(edges, policy, backend_name, max_passes):
         )
         return fn(edges)
     assert backend_name == "mesh"
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.mapreduce import shard_edges
     from repro.graph.edgelist import EdgeList
 
