@@ -11,12 +11,20 @@ instead of compiling them again:
   root (the directory is part of what the cache hits on, so it must not
   move between runs; ``.gitignore`` lists it).
 
+The op metadata (``jax.named_scope`` paths such as ``peel/edge_filter``,
+source lines) is part of the cache key.  JAX leaves it out by default, and
+then a program traced with other scopes, by another version of this code
+sharing the directory, is loaded with that version's op names, and a
+profile of it names its ops wrongly.  Source paths enter the key relative
+to the checkout root, so two checkouts of one commit share entries.
+
 Tests never call this: under the test runner every compile stays in memory.
 """
 
 from __future__ import annotations
 
 import os
+import re
 from typing import Optional
 
 import jax
@@ -44,4 +52,7 @@ def enable_compile_cache(root: Optional[str] = None) -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(_CHECKOUT_ROOT + os.sep))
     return path

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
@@ -870,6 +871,28 @@ def _host_keep_going(prob: Problem, n_s: int, n_t: int) -> bool:
     return n_s > 0
 
 
+class _Transfers:
+    """Bytes one host ladder run copies across the host-device boundary,
+    counted at each copy: ``down`` reads a device array back, ``up`` puts a
+    host value on the device."""
+
+    def __init__(self):
+        self.to_host = 0
+        self.to_device = 0
+
+    def down(self, x) -> np.ndarray:
+        # A jax.Array keeps the host copy of its first read-back, so reading
+        # it again (the same graph solved again) copies nothing.
+        if isinstance(x, jax.Array) and getattr(x, "_npy_value", None) is None:
+            self.to_host += x.nbytes
+        return np.asarray(x)
+
+    def up(self, x, dtype=None) -> jax.Array:
+        a = jnp.asarray(x, dtype)
+        self.to_device += a.nbytes
+        return a
+
+
 def _policy_name(problem: Problem) -> str:
     return {
         "undirected": "undirected_threshold",
@@ -1553,6 +1576,10 @@ class Solver:
                 "single_program": True,
                 "host_round_trips": 0,  # vs one gather/reshard per rung
                 "schedule": [cap * n_shards for cap in schedule],
+                # The graph is resharded on the device; only the rung pass
+                # counts, the pass total and a directed c cross.
+                "bytes_to_host": int(rung_t.nbytes + out.passes.nbytes),
+                "bytes_to_device": 4 if prob.objective == "directed" else 0,
             }
             return out, ladder, hit
 
@@ -1628,13 +1655,16 @@ class Solver:
         n0 = graph.n_nodes
         mp = prob.resolved_max_passes(n0)
         dtype = graph.weight.dtype
+        span = jax.profiler.TraceAnnotation
+        io = _Transfers()
         # Host-side buffers of the current rung (device arrays are rebuilt
         # per segment; each rung is half the size, so total transfer/gather
         # work telescopes to O(m)).
-        src = np.asarray(graph.src)
-        dst = np.asarray(graph.dst)
-        w = np.asarray(graph.weight)
-        msk = np.asarray(graph.mask)
+        with span("densest.ladder.graph_to_host", n_buf=n0, m_buf=len(graph.src)):
+            src = io.down(graph.src)
+            dst = io.down(graph.dst)
+            w = io.down(graph.weight)
+            msk = io.down(graph.mask)
         id_map = np.arange(n0, dtype=np.int64)  # compact id -> original id
         n_cur = n0
         s_al = np.ones(n0, bool)
@@ -1671,149 +1701,163 @@ class Solver:
             compact_below = None
             if prob.compaction == "geometric" and not no_more_compact:
                 compact_below = max(len(src) // 2, 1)
+            shape = dict(rung=seg_idx, n_buf=n_cur, m_buf=len(src))
 
-            # ---- launch one segment on the current buffer ----
-            edges = EdgeList(
-                src=jnp.asarray(src), dst=jnp.asarray(dst),
-                weight=jnp.asarray(w), mask=jnp.asarray(msk),
-                n_nodes=n_cur, directed=graph.directed,
-            )
-            aux_arrays: Tuple = ()
-            if prob.backend == "pallas":
-                aux_arrays = _tiling_arrays(edges, prob, pow2_pad=True)
-            # Carried segment state, identical on both substrates (must
-            # track the _build_segment_program/_build_mesh_program
-            # signatures): alive bitmap(s), absolute pass counter, entry
-            # alive-edge count, and the runtime c for directed policies.
-            carried: Tuple = (jnp.asarray(s_al),)
-            if directed:
-                carried += (jnp.asarray(t_al),)
-            carried += (
-                jnp.asarray(t_done, jnp.int32),
-                jnp.asarray(cur_alive_edges, jnp.int32),
-            )
-            if directed:
-                carried += (jnp.float32(c),)
-            if prob.substrate == "mesh":
-                from repro.core.mapreduce import shard_edges
+            # ---- put one rung and its carried state on the device ----
+            with span("densest.ladder.upload", **shape):
+                edges = EdgeList(
+                    src=io.up(src), dst=io.up(dst),
+                    weight=io.up(w), mask=io.up(msk),
+                    n_nodes=n_cur, directed=graph.directed,
+                )
+                aux_arrays: Tuple = ()
+                if prob.backend == "pallas":
+                    aux_arrays = _tiling_arrays(edges, prob, pow2_pad=True)
+                    # The tiling reads the rung's endpoints back and puts
+                    # its tables on the device.
+                    io.to_host += src.nbytes + dst.nbytes
+                    io.to_device += sum(a.nbytes for a in aux_arrays)
+                # Carried segment state, identical on both substrates (must
+                # track the _build_segment_program/_build_mesh_program
+                # signatures): alive bitmap(s), absolute pass counter, entry
+                # alive-edge count, and the runtime c for directed policies.
+                carried: Tuple = (io.up(s_al),)
+                if directed:
+                    carried += (io.up(t_al),)
+                carried += (
+                    io.up(t_done, jnp.int32),
+                    io.up(cur_alive_edges, jnp.int32),
+                )
+                if directed:
+                    carried += (io.up(c, jnp.float32),)
+                if prob.substrate == "mesh":
+                    from repro.core.mapreduce import shard_edges
 
-                sh = shard_edges(edges, mesh, prob.edge_axes)
-                m_buf = sh.n_edges_padded
-                if compact_below is not None:
-                    compact_below = max(m_buf // 2, 1)
-                fn, hit = self._segment_fn(
-                    prob, seg_mp, compact_below, n_cur, m_buf, dtype, (), mesh
-                )
-                out = fn(sh.src, sh.dst, sh.weight, sh.mask, *carried)
-            else:
-                m_buf = edges.n_edges_padded
-                fn, hit = self._segment_fn(
-                    prob, seg_mp, compact_below, n_cur, m_buf, dtype,
-                    tuple(a.shape for a in aux_arrays), None,
-                )
-                out = fn(edges, *aux_arrays, *carried)
+                    sh = shard_edges(edges, mesh, prob.edge_axes)
+                    m_buf = sh.n_edges_padded
+                    if compact_below is not None:
+                        compact_below = max(m_buf // 2, 1)
+                else:
+                    m_buf = edges.n_edges_padded
+
+            # ---- run the segment; the pass count is the first sync ----
+            with span("densest.ladder.rung", **shape):
+                if prob.substrate == "mesh":
+                    fn, hit = self._segment_fn(
+                        prob, seg_mp, compact_below, n_cur, m_buf, dtype, (), mesh
+                    )
+                    out = fn(sh.src, sh.dst, sh.weight, sh.mask, *carried)
+                else:
+                    fn, hit = self._segment_fn(
+                        prob, seg_mp, compact_below, n_cur, m_buf, dtype,
+                        tuple(a.shape for a in aux_arrays), None,
+                    )
+                    out = fn(edges, *aux_arrays, *carried)
+                t_prev = t_done
+                t_done = int(io.down(out.passes))
             all_hit = all_hit and hit
 
             # ---- fold the segment into the global answer ----
-            t_prev = t_done
-            t_done = int(out.passes)
-            s_al = np.asarray(out.alive)
-            if directed:
-                t_al = np.asarray(out.t_alive)
-            seg_rho = float(out.best_density)
-            if seg_rho > best_rho:  # strict: earliest pass wins ties, as in
-                best_rho = seg_rho  # the single-segment loop
-                ba = np.asarray(out.best_alive)
-                best_alive = np.zeros(n0, bool)
-                best_alive[id_map] = ba[: len(id_map)]
+            with span("densest.ladder.readback", **shape):
+                s_al = io.down(out.alive)
                 if directed:
-                    bt = np.asarray(out.best_t)
-                    best_t = np.zeros(n0, bool)
-                    best_t[id_map] = bt[: len(id_map)]
-                best_size = int(out.best_size)
-            if prob.track_history:
-                shn = np.asarray(out.history_n)
-                sel = shn >= 0
-                hist_n[: len(shn)][sel] = shn[sel]
-                hist_m[: len(shn)][sel] = np.asarray(out.history_m)[sel]
-                hist_rho[: len(shn)][sel] = np.asarray(out.history_rho)[sel]
-            seg_passes = t_done - t_prev
-            slots_scanned += seg_passes * m_buf
-            segments.append(
-                {
-                    "n_buf": int(n_cur),
-                    "m_buf": int(m_buf),
-                    "passes": int(seg_passes),
-                    "compact_below": compact_below,
-                    "cache_hit": bool(hit),
-                }
-            )
-
-            # ---- terminated? ----
-            n_s = int(s_al.sum())
-            n_t = int(t_al.sum()) if directed else n_s
-            if t_done >= mp or not _host_keep_going(prob, n_s, n_t):
+                    t_al = io.down(out.t_alive)
+                seg_rho = float(io.down(out.best_density))
+                if seg_rho > best_rho:  # strict: earliest pass wins ties, as
+                    best_rho = seg_rho  # in the single-segment loop
+                    ba = io.down(out.best_alive)
+                    best_alive = np.zeros(n0, bool)
+                    best_alive[id_map] = ba[: len(id_map)]
+                    if directed:
+                        bt = io.down(out.best_t)
+                        best_t = np.zeros(n0, bool)
+                        best_t[id_map] = bt[: len(id_map)]
+                    best_size = int(io.down(out.best_size))
+                if prob.track_history:
+                    shn = io.down(out.history_n)
+                    sel = shn >= 0
+                    hist_n[: len(shn)][sel] = shn[sel]
+                    hist_m[: len(shn)][sel] = io.down(out.history_m)[sel]
+                    hist_rho[: len(shn)][sel] = io.down(out.history_rho)[sel]
+                seg_passes = t_done - t_prev
+                slots_scanned += seg_passes * m_buf
+                segments.append(
+                    {
+                        "n_buf": int(n_cur),
+                        "m_buf": int(m_buf),
+                        "passes": int(seg_passes),
+                        "compact_below": compact_below,
+                        "cache_hit": bool(hit),
+                    }
+                )
+                n_s = int(s_al.sum())
+                n_t = int(t_al.sum()) if directed else n_s
+                done = t_done >= mp or not _host_keep_going(prob, n_s, n_t)
+            if done:
                 break
 
             # ---- compact survivors into the next bucket ----
-            surv = (s_al | t_al) if directed else s_al
-            ta_np = t_al if directed else s_al
-            ok_e = msk & s_al[src] & ta_np[dst]
-            e_alive = int(ok_e.sum())
-            n_alive = int(surv.sum())
-            new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
-            new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
-            if new_m >= len(src) and new_n >= n_cur:
-                # Bucket floor reached: finish on this buffer uncompacted.
-                no_more_compact = True
-                continue
-            relabel = np.cumsum(surv) - 1  # stable: preserves id order
-            keep = np.nonzero(ok_e)[0]
-            new_src = np.zeros(new_m, src.dtype)
-            new_dst = np.zeros(new_m, dst.dtype)
-            new_w = np.zeros(new_m, w.dtype)
-            new_msk = np.zeros(new_m, bool)
-            new_src[: len(keep)] = relabel[src[keep]]
-            new_dst[: len(keep)] = relabel[dst[keep]]
-            new_w[: len(keep)] = w[keep]
-            new_msk[: len(keep)] = True
-            # id_map covers only the real (unpadded) ids; pad nodes are never
-            # alive, so slicing the survivor mask to its length is exact.
-            id_map = id_map[surv[: len(id_map)]]
-            new_s = np.zeros(new_n, bool)
-            new_s[:n_alive] = s_al[surv]
-            s_al = new_s
-            if directed:
-                new_t = np.zeros(new_n, bool)
-                new_t[:n_alive] = t_al[surv]
-                t_al = new_t
-            src, dst, w, msk = new_src, new_dst, new_w, new_msk
-            n_cur = new_n
-            cur_alive_edges = e_alive
+            with span("densest.ladder.compact", **shape):
+                surv = (s_al | t_al) if directed else s_al
+                ta_np = t_al if directed else s_al
+                ok_e = msk & s_al[src] & ta_np[dst]
+                e_alive = int(ok_e.sum())
+                n_alive = int(surv.sum())
+                new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
+                new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
+                if new_m >= len(src) and new_n >= n_cur:
+                    # Bucket floor reached: finish on this buffer uncompacted.
+                    no_more_compact = True
+                    continue
+                relabel = np.cumsum(surv) - 1  # stable: preserves id order
+                keep = np.nonzero(ok_e)[0]
+                new_src = np.zeros(new_m, src.dtype)
+                new_dst = np.zeros(new_m, dst.dtype)
+                new_w = np.zeros(new_m, w.dtype)
+                new_msk = np.zeros(new_m, bool)
+                new_src[: len(keep)] = relabel[src[keep]]
+                new_dst[: len(keep)] = relabel[dst[keep]]
+                new_w[: len(keep)] = w[keep]
+                new_msk[: len(keep)] = True
+                # id_map covers only the real (unpadded) ids; pad nodes are
+                # never alive, so slicing the survivor mask to its length is
+                # exact.
+                id_map = id_map[surv[: len(id_map)]]
+                new_s = np.zeros(new_n, bool)
+                new_s[:n_alive] = s_al[surv]
+                s_al = new_s
+                if directed:
+                    new_t = np.zeros(new_n, bool)
+                    new_t[:n_alive] = t_al[surv]
+                    t_al = new_t
+                src, dst, w, msk = new_src, new_dst, new_w, new_msk
+                n_cur = new_n
+                cur_alive_edges = e_alive
         else:
             raise RuntimeError(
                 f"compaction ladder exceeded {_COMPACT_MAX_SEGMENTS} segments"
             )
 
         # ---- map the final state back to the original id space ----
-        alive_full = np.zeros(n0, bool)
-        alive_full[id_map] = s_al[: len(id_map)]
-        if directed:
-            t_full = np.zeros(n0, bool)
-            t_full[id_map] = t_al[: len(id_map)]
-        empty = jnp.zeros((0,), bool)
-        outcome = PeelOutcome(
-            best_alive=jnp.asarray(best_alive),
-            best_t=jnp.asarray(best_t) if directed else empty,
-            best_density=jnp.asarray(best_rho, jnp.float32),
-            best_size=jnp.asarray(best_size, jnp.int32),
-            passes=jnp.asarray(t_done, jnp.int32),
-            alive=jnp.asarray(alive_full),
-            t_alive=jnp.asarray(t_full) if directed else empty,
-            history_n=jnp.asarray(hist_n),
-            history_m=jnp.asarray(hist_m),
-            history_rho=jnp.asarray(hist_rho),
-        )
+        with span("densest.ladder.finish", n_buf=n0):
+            alive_full = np.zeros(n0, bool)
+            alive_full[id_map] = s_al[: len(id_map)]
+            if directed:
+                t_full = np.zeros(n0, bool)
+                t_full[id_map] = t_al[: len(id_map)]
+            empty = jnp.zeros((0,), bool)
+            outcome = PeelOutcome(
+                best_alive=io.up(best_alive),
+                best_t=io.up(best_t) if directed else empty,
+                best_density=io.up(best_rho, jnp.float32),
+                best_size=io.up(best_size, jnp.int32),
+                passes=io.up(t_done, jnp.int32),
+                alive=io.up(alive_full),
+                t_alive=io.up(t_full) if directed else empty,
+                history_n=io.up(hist_n),
+                history_m=io.up(hist_m),
+                history_rho=io.up(hist_rho),
+            )
         ladder = {
             "mode": prob.compaction,
             "segments": segments,
@@ -1823,6 +1867,8 @@ class Solver:
             # Each rung is its own program launch, with a host
             # gather/relabel (and reshard, on mesh) between rungs.
             "host_round_trips": len(segments),
+            "bytes_to_host": io.to_host,
+            "bytes_to_device": io.to_device,
         }
         return outcome, ladder, all_hit
 
@@ -1897,6 +1943,7 @@ class Solver:
         return DenseSubgraphResult.from_outcome(out, provenance=prov, extras=extras)
 
     # -- solve --------------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="densest.solve")
     def solve(
         self,
         graph: EdgeList,
@@ -2217,6 +2264,7 @@ class Solver:
         return self._wrap(out, prob, graph.n_nodes, mp, cache_hit=False, extras=extras)
 
     # -- solve_batch --------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="densest.solve_batch")
     def solve_batch(
         self,
         graph: Union[EdgeList, Sequence[EdgeList]],

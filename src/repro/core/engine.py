@@ -434,6 +434,7 @@ def _count_ok(backend, ok: jax.Array) -> jax.Array:
     return jnp.sum(ok.astype(jnp.int32))
 
 
+@jax.named_scope("peel")
 def run_peel(
     edges: EdgeList,
     policy: RemovalPolicy,
@@ -478,6 +479,11 @@ def run_peel(
     and its count at exit — exactly the survivor set an in-program
     compaction needs, already computed by the final pass (the single-program
     mesh ladder reuses it instead of paying another O(m) filter).
+
+    Every op carries its phase in its name: ``peel/.../edge_filter``,
+    ``degree_count``, ``best_set`` or ``removal`` (``jax.named_scope``;
+    op metadata only, the compiled program is the same), so a device
+    trace attributes its time to the pass body's phases.
     """
     n = edges.n_nodes
     directed = policy.directed
@@ -509,37 +515,42 @@ def run_peel(
         # (3) of §5.2: the per-pass edge filter against the alive bitmap(s).
         # Compacted segments carry it from the previous pass's removal, so
         # it is computed exactly once per pass either way.
-        if compact_below is not None:
-            ok = s.edge_ok
-        else:
-            ok = edges.mask & s.alive[edges.src] & ta[edges.dst]
-        w_alive = jnp.where(ok, edges.weight, 0.0)
+        with jax.named_scope("edge_filter"):
+            if compact_below is not None:
+                ok = s.edge_ok
+            else:
+                ok = edges.mask & s.alive[edges.src] & ta[edges.dst]
         # (2): the degree count — the only backend-dependent step.
-        if directed:
-            deg_s, deg_t, total = backend.directed(edges, w_alive)
-        else:
-            deg_s, total = backend.undirected(edges, w_alive)
-            deg_t = deg_s
+        with jax.named_scope("degree_count"):
+            w_alive = jnp.where(ok, edges.weight, 0.0)
+            if directed:
+                deg_s, deg_t, total = backend.directed(edges, w_alive)
+            else:
+                deg_s, total = backend.undirected(edges, w_alive)
+                deg_t = deg_s
         # (1): density + best-intermediate-set tracking.
-        n_s, n_t = counts(s)
-        rho = policy.density(total, n_s, n_t)
-        stats = PassStats(rho=rho, total=total, n_s=n_s, n_t=n_t)
+        with jax.named_scope("best_set"):
+            n_s, n_t = counts(s)
+            rho = policy.density(total, n_s, n_t)
+            stats = PassStats(rho=rho, total=total, n_s=n_s, n_t=n_t)
 
-        improved = policy.eligible(n_s, n_t) & (rho > s.best_rho)
-        best_alive = jnp.where(improved, s.alive, s.best_alive)
-        best_t = jnp.where(improved, ta, s.best_t) if directed else s.best_t
-        best_rho = jnp.where(improved, rho, s.best_rho)
-        best_size = jnp.where(improved, n_s, s.best_size)
+            improved = policy.eligible(n_s, n_t) & (rho > s.best_rho)
+            best_alive = jnp.where(improved, s.alive, s.best_alive)
+            best_t = jnp.where(improved, ta, s.best_t) if directed else s.best_t
+            best_rho = jnp.where(improved, rho, s.best_rho)
+            best_size = jnp.where(improved, n_s, s.best_size)
 
-        rm_s, rm_t = policy.removal(s.alive, ta, deg_s, deg_t, stats)
-        alive = s.alive & ~rm_s
-        t_alive = (ta & ~rm_t) if directed else s.t_alive
+        with jax.named_scope("removal"):
+            rm_s, rm_t = policy.removal(s.alive, ta, deg_s, deg_t, stats)
+            alive = s.alive & ~rm_s
+            t_alive = (ta & ~rm_t) if directed else s.t_alive
 
         if compact_below is not None:
-            ok_next = edges.mask & alive[edges.src] & (
-                t_alive if directed else alive
-            )[edges.dst]
-            ae = _count_ok(backend, ok_next)
+            with jax.named_scope("edge_filter"):
+                ok_next = edges.mask & alive[edges.src] & (
+                    t_alive if directed else alive
+                )[edges.dst]
+                ae = _count_ok(backend, ok_next)
         else:
             ok_next, ae = s.edge_ok, s.alive_edges
 
@@ -555,19 +566,20 @@ def run_peel(
         )
 
     if compact_below is not None:
-        if init_ok_from_mask:
-            ok0 = edges.mask
-        else:
-            # One O(m) filter at segment entry; pass 1 reuses it.
-            ok0 = (
-                edges.mask
-                & alive0[edges.src]
-                & (ta0 if directed else alive0)[edges.dst]
-            )
-        if init_alive_edges is not None:
-            ae0 = jnp.asarray(init_alive_edges, jnp.int32)
-        else:
-            ae0 = _count_ok(backend, ok0)
+        with jax.named_scope("edge_filter"):
+            if init_ok_from_mask:
+                ok0 = edges.mask
+            else:
+                # One O(m) filter at segment entry; pass 1 reuses it.
+                ok0 = (
+                    edges.mask
+                    & alive0[edges.src]
+                    & (ta0 if directed else alive0)[edges.dst]
+                )
+            if init_alive_edges is not None:
+                ae0 = jnp.asarray(init_alive_edges, jnp.int32)
+            else:
+                ae0 = _count_ok(backend, ok0)
     else:
         ok0 = jnp.zeros((0,), bool)
         ae0 = jnp.asarray(0, jnp.int32)

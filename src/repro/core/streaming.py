@@ -30,11 +30,9 @@ the paper's own workload):
 
 from __future__ import annotations
 
-import collections
 import os
 import shutil
 import threading
-import time
 import warnings
 from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
@@ -48,9 +46,6 @@ from repro import constants, faults
 
 Chunk = Tuple[np.ndarray, np.ndarray, np.ndarray]  # (src, dst, weight)
 
-# Rolling bound on the per-chunk timing record (straggler observability
-# without a per-chunk-per-pass host-memory leak on million-chunk streams).
-_TIMINGS_WINDOW = 4096
 # How many times a FAILED chunk (no success yet, no duplicate in flight) is
 # re-issued before its error surfaces.  Counted separately from straggler
 # speculation, so a speculated chunk keeps its full retry budget; a
@@ -166,11 +161,8 @@ class StreamingDensest:
         self.prefetch = prefetch
         self.spill_dir = spill_dir
         self.residency_cap_edges = residency_cap_edges
-        # Observability (host-memory-bounded): a rolling window of chunk
-        # timings plus peak-residency high-water marks.
-        self.chunk_timings: collections.deque = collections.deque(
-            maxlen=_TIMINGS_WINDOW
-        )
+        # Observability: speculation and compaction counts plus
+        # peak-residency high-water marks.
         self.speculative_reissues = 0
         self.compactions = 0  # geometric: stream rebuilds performed
         self.spill_rungs = 0  # geometric: rebuilds that went to disk
@@ -297,7 +289,6 @@ class StreamingDensest:
         lock = threading.Lock()
 
         def work(idx: int, chunk: Chunk) -> int:
-            t0 = time.perf_counter()
             # Chaos hook: every ATTEMPT (first issue, speculative duplicate,
             # retry) of a chunk is one hit at this site, keyed by chunk
             # index — so tests drive the real retry/speculation machinery.
@@ -312,7 +303,6 @@ class StreamingDensest:
                 # already-reduced chunk must not re-enter ``done``.
                 if idx not in done and idx in resident:
                     done[idx] = out
-                self.chunk_timings.append(time.perf_counter() - t0)
             return idx
 
         prelude_ran = prelude is None

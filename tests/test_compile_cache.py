@@ -54,3 +54,75 @@ def test_compile_cache_location(tmp_path, with_env):
     else:
         assert used == str(fallback)
         assert any(e.endswith("-cache") for e in _entries(fallback))
+
+
+_SCOPED = """
+import sys
+import jax, jax.numpy as jnp
+from repro.compile_cache import enable_compile_cache
+enable_compile_cache(root=sys.argv[1])
+
+def f(x):
+    with jax.named_scope(sys.argv[2]):
+        return x * 3 + 1
+
+jax.jit(f)(jnp.arange(16.0)).block_until_ready()
+"""
+
+
+def _run_code(code, src, args, env_dir):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = str(src)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run(
+        [sys.executable, "-c", code, *map(str, args)],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+def _programs(path, name):
+    return [e for e in _entries(path)
+            if e.startswith(name + "-") and e.endswith("-cache")]
+
+
+def test_compile_cache_keys_on_op_names(tmp_path):
+    """A program whose ops carry other scope names is compiled anew, not
+    loaded with the names of the one cached first; the same names hit."""
+    cache = tmp_path / "cache"
+    src = os.path.join(_REPO, "src")
+    _run_code(_SCOPED, src, [tmp_path, "edge_filter"], cache)
+    first = _programs(cache, "jit_f")
+    assert len(first) == 1
+    _run_code(_SCOPED, src, [tmp_path, "edge_filter"], cache)
+    assert _programs(cache, "jit_f") == first
+    _run_code(_SCOPED, src, [tmp_path, "degree_count"], cache)
+    assert len(_programs(cache, "jit_f")) == 2
+
+
+_FROM_PACKAGE = """
+import sys
+import jax, jax.numpy as jnp
+from repro.compile_cache import enable_compile_cache
+from repro.core import engine
+enable_compile_cache(root=sys.argv[1])
+jax.jit(engine.removal_threshold, static_argnums=0)(0.5, jnp.float32(2.0))
+"""
+
+
+def test_compile_cache_shared_across_checkouts(tmp_path):
+    """Two checkouts of the same code at different paths hit one entry:
+    source paths enter the key relative to the checkout root."""
+    import shutil
+
+    cache = tmp_path / "cache"
+    for name in ("a", "b"):
+        shutil.copytree(os.path.join(_REPO, "src", "repro"),
+                        tmp_path / name / "src" / "repro",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    _run_code(_FROM_PACKAGE, tmp_path / "a" / "src", [tmp_path / "a"], cache)
+    first = _programs(cache, "jit_removal_threshold")
+    assert len(first) == 1
+    _run_code(_FROM_PACKAGE, tmp_path / "b" / "src", [tmp_path / "b"], cache)
+    assert _programs(cache, "jit_removal_threshold") == first

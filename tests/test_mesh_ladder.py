@@ -124,6 +124,9 @@ def test_single_device_mesh_ladder_degenerates_to_jit_ladder(eps, small_ladder_f
     lad = mesh_ladder.extras["compaction"]
     assert lad["single_program"] is True
     assert lad["host_round_trips"] == 0
+    # Only the per-rung pass counts and the pass total come back.
+    assert lad["bytes_to_host"] == 4 * len(lad["segments"]) + 4
+    assert lad["bytes_to_device"] == 0
     assert sum(seg["passes"] for seg in lad["segments"]) == int(off.passes)
     # The in-program compaction must actually run (multi-rung schedule);
     # with the production floor these graphs would be one trivial rung.

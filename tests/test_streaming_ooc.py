@@ -14,7 +14,6 @@ import pytest
 from repro.core import Problem, Solver, densest_subgraph
 from repro.core.streaming import (
     StreamingDensest,
-    _TIMINGS_WINDOW,
     chunked_from_arrays,
     chunked_from_memmap,
 )
@@ -176,18 +175,6 @@ def test_async_pipeline_bit_identical_to_sync(graph, chunk):
     assert (st.alive == sync.alive).all()
     assert st.pass_idx == sync.pass_idx
     assert st.history == sync.history
-
-
-def test_chunk_timings_bounded(graph):
-    """The straggler-timing record is a rolling window, not a per-chunk
-    per-pass leak."""
-    edges, (src, dst, w) = graph
-    drv = StreamingDensest(
-        chunked_from_arrays(src, dst, w, chunk=32), n_nodes=edges.n_nodes
-    )
-    drv.run(resume=False)
-    assert drv.chunk_timings.maxlen == _TIMINGS_WINDOW
-    assert len(drv.chunk_timings) <= _TIMINGS_WINDOW
 
 
 # ---------------------------------------------------------------------------
