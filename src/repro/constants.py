@@ -34,6 +34,16 @@ COMPACT_MIN_EDGES = 256
 COMPACT_MIN_NODES = 128
 # Runaway guard on ladder depth; real ladders are O(log m) segments.
 COMPACT_MAX_SEGMENTS = 64
+# A rung with at least this many edge slots compacts chunk-parallel on a
+# thread pool; a smaller one (every test graph, serving ego-net and late
+# rung) compacts as one chunk, inline, where threads would cost more than
+# the gather.
+COMPACT_PARALLEL_MIN_EDGES = 2**20
+# Pool workers: one per available core, at most this many (the gathers are
+# memory-bound); a parallel rung is cut into this many chunks per worker,
+# so a chunk dense in survivors does not hold up the rest.
+COMPACT_MAX_WORKERS = 16
+COMPACT_CHUNKS_PER_WORKER = 4
 
 # --- single-program mesh ladder (core/api.py, §5.2) -------------------------
 # Rung capacities shrink by this factor.  4 is the measured sweet spot on

@@ -46,10 +46,14 @@ and stay bit-identical to their pre-redesign outputs.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
+import os
+from typing import (
+    Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple, Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +106,7 @@ _AUTO_SKETCH_NODES = 1_000_000
 _COMPACT_MIN_EDGES = constants.COMPACT_MIN_EDGES
 _COMPACT_MIN_NODES = constants.COMPACT_MIN_NODES
 _COMPACT_MAX_SEGMENTS = constants.COMPACT_MAX_SEGMENTS
+_COMPACT_PARALLEL_MIN_EDGES = constants.COMPACT_PARALLEL_MIN_EDGES
 _LADDER_STRIDE = constants.LADDER_STRIDE
 _LADDER_MIN_EDGES = constants.LADDER_MIN_EDGES
 _LOCAL_BUDGET = constants.LOCAL_BUDGET
@@ -891,6 +896,109 @@ class _Transfers:
         a = jnp.asarray(x, dtype)
         self.to_device += a.nbytes
         return a
+
+
+# The host ladder's compaction pool: one worker per available core, capped.
+# Threads start on first use and are joined at interpreter exit.
+_COMPACT_WORKERS = min(
+    len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity")
+    else os.cpu_count() or 1,
+    constants.COMPACT_MAX_WORKERS,
+)
+_COMPACT_POOL = concurrent.futures.ThreadPoolExecutor(
+    _COMPACT_WORKERS, thread_name_prefix="densest-compact"
+)
+
+
+def _compact_chunks(m_buf: int) -> int:
+    """Chunks the host compaction of an ``m_buf``-slot rung is cut into:
+    one below ``_COMPACT_PARALLEL_MIN_EDGES``, else a few per pool worker."""
+    if m_buf < _COMPACT_PARALLEL_MIN_EDGES:
+        return 1
+    return _COMPACT_WORKERS * constants.COMPACT_CHUNKS_PER_WORKER
+
+
+class _Compacted(NamedTuple):
+    """The next rung's host buffers, as :func:`_compact_rung` gathers them."""
+
+    src: np.ndarray
+    dst: np.ndarray
+    w: np.ndarray
+    msk: np.ndarray
+    s_al: np.ndarray
+    t_al: Optional[np.ndarray]
+    id_map: np.ndarray  # compact id -> original id
+    e_alive: int
+    n_alive: int
+
+
+def _compact_rung(
+    src, dst, w, msk, s_al, t_al, id_map, chunks: int
+) -> Optional[_Compacted]:
+    """Gather one rung's survivors into the next power-of-two bucket.
+
+    An edge survives if ``msk & s_al[src] & t_al[dst]`` (``t_al`` is None
+    when undirected: ``s_al`` on both ends); a node if alive on either
+    side.  Both keep their order, and survivors are renumbered densely.
+
+    The edge range is cut into ``chunks`` contiguous chunks.  Phase 1 filters
+    each chunk and counts its survivors; the prefix of the counts places each
+    chunk's survivors in the output, where phase 2 gathers them.  So the
+    buffers are the same for every ``chunks``.  More than one chunk runs on
+    the pool (numpy's filters and gathers release the GIL); the node-sized
+    steps stay serial.
+
+    Returns None where the next bucket would be no smaller than this one
+    (the ladder's floor): the rung then finishes uncompacted.
+    """
+    surv = s_al if t_al is None else s_al | t_al
+    ta = s_al if t_al is None else t_al
+    m = len(src)
+    cuts = [m * i // chunks for i in range(chunks + 1)]
+    run = map if chunks == 1 else _COMPACT_POOL.map
+
+    def survivors(i):
+        a, b = cuts[i], cuts[i + 1]
+        ok = msk[a:b] & s_al[src[a:b]] & ta[dst[a:b]]
+        return ok, int(np.count_nonzero(ok))
+
+    oks, counts = zip(*run(survivors, range(chunks)))
+    ends = np.cumsum(counts)
+    e_alive = int(ends[-1])
+    n_alive = int(np.count_nonzero(surv))
+    new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
+    new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
+    if new_m >= m and new_n >= len(s_al):
+        return None
+    relabel = np.cumsum(surv, dtype=src.dtype) - 1  # stable: keeps id order
+    new_src = np.zeros(new_m, src.dtype)
+    new_dst = np.zeros(new_m, dst.dtype)
+    new_w = np.zeros(new_m, w.dtype)
+    new_msk = np.zeros(new_m, bool)
+    new_msk[:e_alive] = True
+
+    def gather(i):
+        a, b = cuts[i], cuts[i + 1]
+        out = slice(ends[i] - counts[i], ends[i])
+        keep = np.flatnonzero(oks[i])
+        new_src[out] = relabel[src[a:b][keep]]
+        new_dst[out] = relabel[dst[a:b][keep]]
+        new_w[out] = w[a:b][keep]
+
+    for _ in run(gather, range(chunks)):
+        pass
+    # id_map covers only the real (unpadded) ids; pad nodes are never
+    # alive, so slicing the survivor mask to its length is exact.
+    id_map = id_map[surv[: len(id_map)]]
+    new_s = np.zeros(new_n, bool)
+    new_s[:n_alive] = s_al[surv]
+    new_t = None
+    if t_al is not None:
+        new_t = np.zeros(new_n, bool)
+        new_t[:n_alive] = t_al[surv]
+    return _Compacted(
+        new_src, new_dst, new_w, new_msk, new_s, new_t, id_map, e_alive, n_alive
+    )
 
 
 def _policy_name(problem: Problem) -> str:
@@ -1797,42 +1905,17 @@ class Solver:
                 break
 
             # ---- compact survivors into the next bucket ----
-            with span("densest.ladder.compact", **shape):
-                surv = (s_al | t_al) if directed else s_al
-                ta_np = t_al if directed else s_al
-                ok_e = msk & s_al[src] & ta_np[dst]
-                e_alive = int(ok_e.sum())
-                n_alive = int(surv.sum())
-                new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
-                new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
-                if new_m >= len(src) and new_n >= n_cur:
-                    # Bucket floor reached: finish on this buffer uncompacted.
-                    no_more_compact = True
-                    continue
-                relabel = np.cumsum(surv) - 1  # stable: preserves id order
-                keep = np.nonzero(ok_e)[0]
-                new_src = np.zeros(new_m, src.dtype)
-                new_dst = np.zeros(new_m, dst.dtype)
-                new_w = np.zeros(new_m, w.dtype)
-                new_msk = np.zeros(new_m, bool)
-                new_src[: len(keep)] = relabel[src[keep]]
-                new_dst[: len(keep)] = relabel[dst[keep]]
-                new_w[: len(keep)] = w[keep]
-                new_msk[: len(keep)] = True
-                # id_map covers only the real (unpadded) ids; pad nodes are
-                # never alive, so slicing the survivor mask to its length is
-                # exact.
-                id_map = id_map[surv[: len(id_map)]]
-                new_s = np.zeros(new_n, bool)
-                new_s[:n_alive] = s_al[surv]
-                s_al = new_s
-                if directed:
-                    new_t = np.zeros(new_n, bool)
-                    new_t[:n_alive] = t_al[surv]
-                    t_al = new_t
-                src, dst, w, msk = new_src, new_dst, new_w, new_msk
-                n_cur = new_n
-                cur_alive_edges = e_alive
+            chunks = _compact_chunks(len(src))
+            with span("densest.ladder.compact", **shape, chunks=chunks):
+                nxt = _compact_rung(src, dst, w, msk, s_al, t_al, id_map, chunks)
+            if nxt is None:
+                # Bucket floor reached: finish on this buffer uncompacted.
+                no_more_compact = True
+                continue
+            segments[-1]["compact_chunks"] = chunks
+            src, dst, w, msk, s_al, t_al, id_map = nxt[:7]
+            n_cur = len(s_al)
+            cur_alive_edges = nxt.e_alive
         else:
             raise RuntimeError(
                 f"compaction ladder exceeded {_COMPACT_MAX_SEGMENTS} segments"

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Problem, Solver, engine
+from repro.core import Problem, Solver, api, engine
 from repro.graph.generators import planted_dense_subgraph
 
 PHASES = ("edge_filter", "degree_count", "best_set", "removal")
@@ -86,6 +86,45 @@ def test_rung_spans_carry_the_rung_buffers(traced):
              if n == "densest.ladder.rung"]
     assert [(st["n_buf"], st["m_buf"]) for st in rungs] == [
         (seg["n_buf"], seg["m_buf"]) for seg in segs]
+
+
+def _compact_chunks(by_line):
+    return [st["chunks"] for s in by_line.values() for n, _, _, st in s
+            if n == "densest.ladder.compact"]
+
+
+def _compacted_chunks(res):
+    return [seg["compact_chunks"] for seg in res.extras["compaction"]["segments"]
+            if "compact_chunks" in seg]
+
+
+def test_compact_spans_carry_their_chunk_count(traced):
+    """A small rung compacts as one chunk; every rung that compacted says
+    so in ``segments``, the floor's last try (if any) only in its span."""
+    res, by_line = traced
+    chunks = _compact_chunks(by_line)
+    compacted = _compacted_chunks(res)
+    assert compacted and chunks[: len(compacted)] == compacted
+    assert set(chunks) == {1}
+
+
+def test_chunked_compaction_opens_no_span_off_the_solving_thread(
+        tmp_path, monkeypatch):
+    """Chunks run on the pool's threads, which open no span: the ladder's
+    host seconds (the self time of its spans) count each second once."""
+    monkeypatch.setattr(api, "_COMPACT_PARALLEL_MIN_EDGES", 1)
+    g = _graph()
+    solver = Solver()
+    solver.solve(g, _problem())
+    with jax.profiler.trace(str(tmp_path)):
+        res = solver.solve(g, _problem())
+    by_line = _host_spans(str(tmp_path))
+    (spans,) = by_line.values()
+    assert any(n == "densest.solve" for n, *_ in spans)
+    chunks = _compact_chunks(by_line)
+    compacted = _compacted_chunks(res)
+    assert compacted and chunks[: len(compacted)] == compacted
+    assert all(c > 1 for c in chunks)
 
 
 def test_ladder_counts_the_bytes_it_moves():
