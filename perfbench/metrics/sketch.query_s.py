@@ -1,0 +1,12 @@
+"""Device seconds per front-door call in the Count-Sketch query: the ops
+whose framework name lies under ``peel/.../degree_count/sketch_query``
+(node hashes, counter gather and the median over the tables of
+``repro.core.countsketch.SketchBackend``), inside the traced window,
+averaged over the device planes, over the window's calls
+(``perfbench/drivers/sketch_solves.py``)."""
+
+from perfbench.drivers import sketch_solves
+
+
+def read(ctx):
+    return sketch_solves.scope_seconds(ctx, "sketch_query")

@@ -157,6 +157,16 @@ class Problem:
       streaming substrate constrains it).
     * ``sketch_tables`` / ``sketch_buckets`` / ``sketch_seed`` — §5.1
       table geometry; cache-key-exempt unless the sketch backend runs.
+      The default width, 2^13 buckets, is a CPU-test width.  At Table 1
+      scale hub collisions keep most estimates above the removal
+      threshold and the peel stalls until the pass cap: on a Chung-Lu
+      Flickr stand-in (976K nodes, t·b/n = 0.04) it ends at 5% of the
+      exact peel's density.  The benchmark's LiveJournal stand-in (4.84M
+      nodes) needs 2^18 buckets (t·b/n = 0.27) and stalls at 2^17
+      (PERF.md section 4).
+      A sketched solve on the jit substrate reports the table geometry and
+      its endpoint-table updates in ``extras["sketch"]``
+      (:class:`~repro.core.countsketch.SketchCounters`).
     * ``sketch_node_chunk`` — mesh sketch only: degree-query streaming
       chunk (bounds the transient query footprint).
     * ``tile_size`` / ``tile_block`` — Pallas tile geometry;
@@ -2140,7 +2150,15 @@ class Solver:
             out = fn(graph, jnp.float32(prob.c))
         else:
             out = fn(graph, *aux)
-        return self._wrap(out, prob, n, mp, hit)
+        extras = None
+        if prob.backend == "sketch" and degree_fn is None:
+            from repro.core.countsketch import SketchCounters
+
+            extras = {"sketch": SketchCounters(
+                prob.sketch_tables, prob.sketch_buckets,
+                graph.n_edges_padded, out.passes,
+            )}
+        return self._wrap(out, prob, n, mp, hit, extras=extras)
 
     def _directed_grid(
         self, graph: EdgeList, prob: Problem, mp: int, fn, hit: bool

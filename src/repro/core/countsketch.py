@@ -14,8 +14,9 @@ and int32-safe (no x64 requirement).
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from repro.kernels import hashing
 
 __all__ = [
     "SketchBackend",
+    "SketchCounters",
     "SketchParams",
     "densest_subgraph_sketched",
     "make_sketch_params",
@@ -126,23 +128,74 @@ class SketchBackend:
     directed rule keeps SEPARATE out/in tables (accumulate src endpoints
     only / dst endpoints only) so Algorithm 3's out- and in-degree
     estimates stay unbiased for their own side.
+
+    Each half of the work carries its own ``jax.named_scope`` under the
+    engine's ``degree_count`` phase: ``sketch_update`` (endpoint hashes,
+    flat counter index, counter scatter-add) and ``sketch_query`` (node
+    hashes, counter gather, median).  Op metadata only: the compiled
+    program is the same.
     """
 
     def __init__(self, params: SketchParams):
         self.params = params
 
     def undirected(self, edges: EdgeList, w_alive: jax.Array):
-        counters = sketch_degrees_from_edges(self.params, edges, w_alive)
-        nodes = jnp.arange(edges.n_nodes, dtype=jnp.int32)
-        return query_degrees(self.params, counters, nodes), jnp.sum(w_alive)
+        with jax.named_scope("sketch_update"):
+            counters = sketch_degrees_from_edges(self.params, edges, w_alive)
+        with jax.named_scope("sketch_query"):
+            nodes = jnp.arange(edges.n_nodes, dtype=jnp.int32)
+            deg = query_degrees(self.params, counters, nodes)
+        return deg, jnp.sum(w_alive)
 
     def directed(self, edges: EdgeList, w_alive: jax.Array):
-        c_out = sketch_endpoint_counters(self.params, edges.src, w_alive)
-        c_in = sketch_endpoint_counters(self.params, edges.dst, w_alive)
-        nodes = jnp.arange(edges.n_nodes, dtype=jnp.int32)
-        out_deg = query_degrees(self.params, c_out, nodes)
-        in_deg = query_degrees(self.params, c_in, nodes)
+        with jax.named_scope("sketch_update"):
+            c_out = sketch_endpoint_counters(self.params, edges.src, w_alive)
+            c_in = sketch_endpoint_counters(self.params, edges.dst, w_alive)
+        with jax.named_scope("sketch_query"):
+            nodes = jnp.arange(edges.n_nodes, dtype=jnp.int32)
+            out_deg = query_degrees(self.params, c_out, nodes)
+            in_deg = query_degrees(self.params, c_in, nodes)
         return out_deg, in_deg, jnp.sum(w_alive)
+
+
+class SketchCounters(collections.abc.Mapping):
+    """``extras["sketch"]`` of a sketched solve, a read-only mapping:
+
+    * ``tables``, ``buckets`` — the table geometry t × b;
+    * ``counter_bytes`` — the float32 counter table, 4·t·b;
+    * ``slots_hashed`` — endpoint-table updates of the call: every pass
+      hashes both endpoints of every padded edge slot into all t tables
+      (dead slots add weight 0), so passes × 2 × t × padded m.
+
+    ``passes`` may be the solve's device scalar: only ``slots_hashed``
+    reads it, so building the mapping waits for nothing, and reading that
+    key waits for the solve like any read of its answer.
+    """
+
+    KEYS = ("tables", "buckets", "counter_bytes", "slots_hashed")
+
+    def __init__(self, tables: int, buckets: int, edge_slots: int, passes: Any):
+        self._tables = int(tables)
+        self._buckets = int(buckets)
+        self._edge_slots = int(edge_slots)
+        self._passes = passes
+
+    def __getitem__(self, key: str) -> int:
+        if key == "tables":
+            return self._tables
+        if key == "buckets":
+            return self._buckets
+        if key == "counter_bytes":
+            return 4 * self._tables * self._buckets
+        if key == "slots_hashed":
+            return int(self._passes) * 2 * self._tables * self._edge_slots
+        raise KeyError(key)
+
+    def __iter__(self):
+        return iter(self.KEYS)
+
+    def __len__(self) -> int:
+        return len(self.KEYS)
 
 
 def sketched_degree_fn(p: SketchParams):
