@@ -165,7 +165,8 @@ class Problem:
       nodes) needs 2^18 buckets (t·b/n = 0.27) and stalls at 2^17
       (PERF.md section 4).
       A sketched solve on the jit substrate reports the table geometry and
-      its endpoint-table updates in ``extras["sketch"]``
+      the endpoint-table updates of its uncompacted schedule in
+      ``extras["sketch"]``
       (:class:`~repro.core.countsketch.SketchCounters`).
     * ``sketch_node_chunk`` — mesh sketch only: degree-query streaming
       chunk (bounds the transient query footprint).
@@ -270,10 +271,12 @@ class Problem:
       ladder is one compiled collective-only program); ``'twophase'``:
       exactly one compaction after ``twophase_passes`` passes (the
       historical ``make_distributed_peel_twophase`` schedule); ``'auto'``
-      (DEFAULT): geometric for exact/pallas, off for sketch (Count-Sketch
-      estimates hash node ids, so renumbering would change them).
+      (DEFAULT): geometric, but off for sketch on the mesh substrate.
       Compaction is pure renumbering: results are bit-identical to
       ``'off'`` for integer-valued edge weights (e.g. unweighted graphs).
+      A sketched ladder (jit substrate only) keeps node ids and compacts
+      edges only, since Count-Sketch estimates hash node ids; the mesh
+      ladders renumber, so mesh × sketch needs ``'off'``.
       See docs/compaction.md.
     * ``twophase_passes`` — twophase phase-1 pass budget.
     * ``min_deg_fallback`` / ``ceil_count`` — Alg 2 realization variants
@@ -291,8 +294,8 @@ class Problem:
     max_passes: Optional[int] = None  # None -> Lemma 4/13 bound
     track_history: bool = False
     # Compaction runtime (scheduling; never keys compiled programs).  The
-    # default is 'auto' (ROADMAP soak item, flipped after PRs 3-4): exact and
-    # pallas backends ride the geometric ladder by default, sketch stays off.
+    # default is 'auto' (ROADMAP soak item, flipped after PRs 3-4): every
+    # backend rides the geometric ladder by default but mesh × sketch.
     compaction: str = "auto"  # off | twophase | geometric | auto
     twophase_passes: int = 8  # compaction='twophase': phase-1 pass budget
     # Algorithm 2 realization knobs (floor+fallback = single-device legacy,
@@ -477,18 +480,22 @@ class Problem:
             if substrate == "streaming":
                 backend = "exact"
             elif self.compaction in ("geometric", "twophase"):
-                # An explicit compaction request constrains the resolution:
-                # sketch estimates hash node ids, so only exact-arithmetic
-                # backends can ride the ladder.
+                # An explicit compaction request steers the resolution to
+                # the exact backend (answers users already get; the
+                # sketch rides only the jit ladder, with node ids kept).
                 backend = "exact"
             else:
                 backend = "sketch" if n_nodes > _AUTO_SKETCH_NODES else "exact"
         compaction = self.compaction
+        # Count-Sketch estimates hash node ids.  The jit ladder keeps them
+        # for a sketched solve and compacts edges only; the mesh ladders
+        # renumber, which would change the estimates.
+        ladder_keeps_ids = substrate == "jit"
         if compaction == "auto":
-            # Geometric compaction is pure renumbering for exact-arithmetic
-            # backends; Count-Sketch estimates hash node ids, so renumbering
-            # would change them — auto keeps sketch runs uncompacted.
-            compaction = "geometric" if backend in ("exact", "pallas") else "off"
+            compaction = (
+                "off" if backend == "sketch" and not ladder_keeps_ids
+                else "geometric"
+            )
         p = self
         if (
             backend != self.backend
@@ -498,10 +505,11 @@ class Problem:
             p = dataclasses.replace(
                 self, backend=backend, substrate=substrate, compaction=compaction
             )
-        if p.compaction != "off" and p.backend == "sketch":
+        if p.compaction != "off" and p.backend == "sketch" and not ladder_keeps_ids:
             raise ValueError(
-                "compaction renumbers node ids, which changes Count-Sketch "
-                "degree estimates; backend='sketch' needs compaction='off'"
+                "Count-Sketch degree estimates hash node ids, and only the jit "
+                "ladder keeps them; backend='sketch' with "
+                f"substrate={p.substrate!r} needs compaction='off'"
             )
         if p.compaction == "twophase" and p.substrate == "streaming":
             raise ValueError(
@@ -942,14 +950,26 @@ class _Compacted(NamedTuple):
     n_alive: int
 
 
+def _at_floor(m: int, n: int, e_alive: int, n_alive: int, keep_ids: bool) -> bool:
+    """The ladder's floor: ``e_alive`` edges and ``n_alive`` nodes would
+    fill buckets no smaller than this rung's ``m`` and ``n`` (with node ids
+    kept, the edge bucket alone)."""
+    new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
+    new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
+    return new_m >= m and (keep_ids or new_n >= n)
+
+
 def _compact_rung(
-    src, dst, w, msk, s_al, t_al, id_map, chunks: int
+    src, dst, w, msk, s_al, t_al, id_map, chunks: int, keep_ids: bool = False
 ) -> Optional[_Compacted]:
     """Gather one rung's survivors into the next power-of-two bucket.
 
     An edge survives if ``msk & s_al[src] & t_al[dst]`` (``t_al`` is None
     when undirected: ``s_al`` on both ends); a node if alive on either
     side.  Both keep their order, and survivors are renumbered densely.
+    With ``keep_ids`` only the edges are compacted: their endpoints keep
+    their ids, and the node arrays and ``id_map`` are returned as they are
+    (a degree backend that hashes node ids needs the original ones).
 
     The edge range is cut into ``chunks`` contiguous chunks.  Phase 1 filters
     each chunk and counts its survivors; the prefix of the counts places each
@@ -958,8 +978,8 @@ def _compact_rung(
     the pool (numpy's filters and gathers release the GIL); the node-sized
     steps stay serial.
 
-    Returns None where the next bucket would be no smaller than this one
-    (the ladder's floor): the rung then finishes uncompacted.
+    Returns None at the ladder's floor (:func:`_at_floor`): the rung then
+    finishes uncompacted.
     """
     surv = s_al if t_al is None else s_al | t_al
     ta = s_al if t_al is None else t_al
@@ -976,11 +996,11 @@ def _compact_rung(
     ends = np.cumsum(counts)
     e_alive = int(ends[-1])
     n_alive = int(np.count_nonzero(surv))
-    new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
-    new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
-    if new_m >= m and new_n >= len(s_al):
+    if _at_floor(m, len(s_al), e_alive, n_alive, keep_ids):
         return None
-    relabel = np.cumsum(surv, dtype=src.dtype) - 1  # stable: keeps id order
+    new_m = pow2_bucket(max(e_alive, 1), _COMPACT_MIN_EDGES)
+    if not keep_ids:
+        relabel = np.cumsum(surv, dtype=src.dtype) - 1  # stable: keeps id order
     new_src = np.zeros(new_m, src.dtype)
     new_dst = np.zeros(new_m, dst.dtype)
     new_w = np.zeros(new_m, w.dtype)
@@ -991,15 +1011,24 @@ def _compact_rung(
         a, b = cuts[i], cuts[i + 1]
         out = slice(ends[i] - counts[i], ends[i])
         keep = np.flatnonzero(oks[i])
-        new_src[out] = relabel[src[a:b][keep]]
-        new_dst[out] = relabel[dst[a:b][keep]]
+        if keep_ids:
+            new_src[out] = src[a:b][keep]
+            new_dst[out] = dst[a:b][keep]
+        else:
+            new_src[out] = relabel[src[a:b][keep]]
+            new_dst[out] = relabel[dst[a:b][keep]]
         new_w[out] = w[a:b][keep]
 
     for _ in run(gather, range(chunks)):
         pass
+    if keep_ids:
+        return _Compacted(
+            new_src, new_dst, new_w, new_msk, s_al, t_al, id_map, e_alive, n_alive
+        )
     # id_map covers only the real (unpadded) ids; pad nodes are never
     # alive, so slicing the survivor mask to its length is exact.
     id_map = id_map[surv[: len(id_map)]]
+    new_n = pow2_bucket(max(n_alive, 1), _COMPACT_MIN_NODES)
     new_s = np.zeros(new_n, bool)
     new_s[:n_alive] = s_al[surv]
     new_t = None
@@ -1755,7 +1784,11 @@ class Solver:
         the pass-by-pass removal decisions — and therefore best set, best
         density, final bitmaps, pass count and history — are bit-identical
         to the uncompacted loop for integer-valued edge weights, and equal
-        up to float reassociation otherwise.  ``compaction='twophase'``
+        up to float reassociation otherwise.  A sketched solve's rungs keep
+        node ids and compact edges only (:func:`_compact_rung`'s
+        ``keep_ids``): Count-Sketch hashes see the original ids, so its
+        counters, and with them every decision, are those of the
+        uncompacted loop too.  ``compaction='twophase'``
         reuses the same machinery with a fixed schedule: one compaction
         after ``twophase_passes`` passes (the historical
         ``make_distributed_peel_twophase`` recipe).
@@ -1807,6 +1840,8 @@ class Solver:
         # read by rungs entered right after (re)initialization, where it is
         # exact — terminal (compact_below=None) segments ignore it.
         cur_alive_edges = int(msk.sum())
+        # Count-Sketch degrees hash node ids: its rungs compact edges only.
+        keep_ids = prob.backend == "sketch"
         twophase = prob.compaction == "twophase"
         # twophase_passes >= 1 is Problem-validated; mp=0 must stay 0 so a
         # zero-budget run executes no passes, exactly like 'off'.
@@ -1817,6 +1852,9 @@ class Solver:
         for seg_idx in range(_COMPACT_MAX_SEGMENTS):
             seg_mp = tp_k1 if (twophase and seg_idx == 0) else mp
             compact_below = None
+            # With node ids kept, a rung at the floor even with no survivor
+            # left gets no trigger (no 0-pass round trip).
+            no_more_compact |= keep_ids and _at_floor(len(src), n_cur, 0, 0, True)
             if prob.compaction == "geometric" and not no_more_compact:
                 compact_below = max(len(src) // 2, 1)
             shape = dict(rung=seg_idx, n_buf=n_cur, m_buf=len(src))
@@ -1917,7 +1955,9 @@ class Solver:
             # ---- compact survivors into the next bucket ----
             chunks = _compact_chunks(len(src))
             with span("densest.ladder.compact", **shape, chunks=chunks):
-                nxt = _compact_rung(src, dst, w, msk, s_al, t_al, id_map, chunks)
+                nxt = _compact_rung(
+                    src, dst, w, msk, s_al, t_al, id_map, chunks, keep_ids
+                )
             if nxt is None:
                 # Bucket floor reached: finish on this buffer uncompacted.
                 no_more_compact = True
@@ -2009,7 +2049,15 @@ class Solver:
             return self._wrap(best, prob, n, mp, all_hit, extras=extras)
         c = prob.c if prob.objective == "directed" else None
         out, ladder, hit = runner(graph, prob, mesh, c)
-        return self._wrap(out, prob, n, mp, hit, extras={"compaction": ladder})
+        extras = {"compaction": ladder}
+        if prob.backend == "sketch":
+            from repro.core.countsketch import SketchCounters
+
+            extras["sketch"] = SketchCounters(
+                prob.sketch_tables, prob.sketch_buckets,
+                graph.n_edges_padded, out.passes,
+            )
+        return self._wrap(out, prob, n, mp, hit, extras=extras)
 
     # -- result wrapping ----------------------------------------------------
     def _wrap(
@@ -2075,9 +2123,9 @@ class Solver:
             and prob.compaction != "off"
             and problem.compaction == "auto"
         ):
-            # Like the sketch downgrade in resolve(): a degree_fn hook binds
-            # one fixed graph, so 'auto' (the default) falls back to the
-            # uncompacted loop instead of erroring — only an EXPLICIT
+            # Like the mesh sketch downgrade in resolve(): a degree_fn hook
+            # binds one fixed graph, so 'auto' (the default) falls back to
+            # the uncompacted loop instead of erroring — only an EXPLICIT
             # ladder request conflicts with the hook.
             prob = dataclasses.replace(prob, compaction="off")
         if prob.substrate != "streaming" and (checkpoint_dir is not None or resume):

@@ -163,9 +163,12 @@ class SketchCounters(collections.abc.Mapping):
 
     * ``tables``, ``buckets`` — the table geometry t × b;
     * ``counter_bytes`` — the float32 counter table, 4·t·b;
-    * ``slots_hashed`` — endpoint-table updates of the call: every pass
-      hashes both endpoints of every padded edge slot into all t tables
-      (dead slots add weight 0), so passes × 2 × t × padded m.
+    * ``slots_hashed`` — endpoint-table updates of the uncompacted
+      schedule: every pass hashes both endpoints of every padded edge slot
+      of the input into all t tables (dead slots add weight 0), so
+      passes × 2 × t × padded m.  A solve on the host compaction ladder
+      hashes each rung's buffer only, 2 × t ×
+      ``extras["compaction"]["edge_slots_scanned"]`` updates in all.
 
     ``passes`` may be the solve's device scalar: only ``slots_hashed``
     reads it, so building the mapping waits for nothing, and reading that

@@ -603,18 +603,24 @@ def test_compaction_zero_pass_runs_match_off(mode):
 def test_compaction_auto_resolution_and_validation():
     # 'auto' is the DEFAULT since the ROADMAP flip (PR 5).
     assert Problem().compaction == "auto"
-    # auto -> geometric for exact, off for sketch.
+    # auto -> geometric for exact, and for sketch on the jit substrate.
     assert Problem.undirected(compaction="auto").resolve(100).compaction == "geometric"
     # An explicit ladder steers backend='auto' to exact even above the
-    # sketch threshold (sketch can't ride the ladder).
+    # sketch threshold.
     big = Problem.undirected(backend="auto", compaction="geometric").resolve(2_000_000)
     assert big.backend == "exact" and big.compaction == "geometric"
     assert (
         Problem.undirected(backend="sketch", compaction="auto").resolve(100).compaction
-        == "off"
+        == "geometric"
     )
+    for mode in ("geometric", "twophase"):
+        sk = Problem.undirected(backend="sketch", compaction=mode).resolve(100)
+        assert (sk.backend, sk.substrate, sk.compaction) == ("sketch", "jit", mode)
+    # The mesh ladders renumber node ids: a sketch there stays off.
+    mesh_sk = Problem.undirected(backend="sketch", substrate="mesh")
+    assert mesh_sk.resolve(100).compaction == "off"
     with pytest.raises(ValueError):
-        Problem.undirected(backend="sketch", compaction="geometric").resolve(100)
+        dataclasses.replace(mesh_sk, compaction="geometric").resolve(100)
     with pytest.raises(ValueError):
         Problem.undirected(substrate="streaming", compaction="twophase").resolve(100)
     with pytest.raises(ValueError):

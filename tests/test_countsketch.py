@@ -98,9 +98,9 @@ def _chung_lu(seed):
 
 
 def _sketch_problem(b, **kw):
+    kw.setdefault("compaction", "auto")
     return Problem(objective="undirected", eps=0.5, backend="sketch",
-                   sketch_tables=T, sketch_buckets=b, sketch_seed=0,
-                   compaction="auto", **kw)
+                   sketch_tables=T, sketch_buckets=b, sketch_seed=0, **kw)
 
 
 @pytest.mark.parametrize("seed", [5, 7])
@@ -124,13 +124,20 @@ def test_sketched_solve_equals_the_numpy_reference(seed, b):
 
 
 def test_sketched_solve_counts_its_hashed_slots():
+    """``slots_hashed`` counts the uncompacted schedule on and off the
+    ladder; the ladder's rungs scan fewer slots than that."""
     g, _, _ = _chung_lu(5)
-    res = Solver().solve(g, _sketch_problem(256))
-    sk = res.extras["sketch"]
-    assert dict(sk) == {
-        "tables": T, "buckets": 256, "counter_bytes": 4 * T * 256,
-        "slots_hashed": int(res.passes) * 2 * T * g.n_edges_padded,
-    }
+    on = Solver().solve(g, _sketch_problem(256))
+    off = Solver().solve(g, _sketch_problem(256, compaction="off"))
+    for res in (on, off):
+        assert dict(res.extras["sketch"]) == {
+            "tables": T, "buckets": 256, "counter_bytes": 4 * T * 256,
+            "slots_hashed": int(res.passes) * 2 * T * g.n_edges_padded,
+        }
+    assert "compaction" not in off.extras
+    ladder = on.extras["compaction"]
+    assert len(ladder["segments"]) > 1
+    assert ladder["edge_slots_scanned"] < int(on.passes) * g.n_edges_padded
     assert Solver().solve(g, Problem.undirected(eps=0.5)).extras.get("sketch") is None
 
 
@@ -145,6 +152,75 @@ def test_sketch_counters_wait_for_nothing_until_slots_are_read():
     with pytest.raises(AssertionError):
         sk["slots_hashed"]
     assert SketchCounters(5, 8, 1000, jnp.int32(3))["slots_hashed"] == 30000
+
+
+# ---------------------------------------------------------------------------
+# Sketched solves on the host compaction ladder: the rungs keep node ids, so
+# every counter, and with it every decision, is the uncompacted loop's.
+# ---------------------------------------------------------------------------
+
+LADDER_FIELDS = ("best_alive", "best_t", "best_density", "best_size", "passes",
+                 "alive", "t_alive", "history_n", "history_m", "history_rho")
+OBJECTIVES = {
+    "undirected": lambda **kw: Problem.undirected(eps=0.5, **kw),
+    "at_least_k": lambda **kw: Problem.at_least_k(k=300, eps=0.5, **kw),
+    "directed": lambda **kw: Problem.directed(c=1.0, eps=0.5, **kw),
+}
+
+
+def _weighted(seed, directed):
+    """A Chung-Lu graph with integer weights 1-4 and padded slots."""
+    src, dst = graphgen.generate(N_NODES, N_EDGES, 2.2, seed)
+    w = np.random.default_rng(seed).integers(1, 5, N_EDGES).astype(np.float32)
+    g = EdgeList(src=src, dst=dst, weight=jnp.asarray(w),
+                 mask=jnp.ones((N_EDGES,), bool), n_nodes=N_NODES,
+                 directed=directed)
+    return g.with_padding(1 << 14)
+
+
+def _sketched(objective, **kw):
+    return OBJECTIVES[objective](
+        backend="sketch", sketch_tables=T, sketch_buckets=64, sketch_seed=3,
+        track_history=True, **kw)
+
+
+@pytest.mark.parametrize("seed", [5, 11])
+@pytest.mark.parametrize("objective", sorted(OBJECTIVES))
+def test_sketched_ladder_answers_as_the_uncompacted_loop(objective, seed):
+    """64 buckets for 3000 nodes: counters collide, estimates are far off,
+    and the ladder still answers bit for bit as ``compaction='off'``."""
+    g = _weighted(seed, objective == "directed")
+    solver = Solver()
+    on = solver.solve(g, _sketched(objective))
+    off = solver.solve(g, _sketched(objective, compaction="off"))
+    assert on.provenance.compaction == "geometric"
+    assert len(on.extras["compaction"]["segments"]) > 1
+    for field in LADDER_FIELDS:
+        a, b = np.asarray(getattr(on, field)), np.asarray(getattr(off, field))
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), field
+
+
+@pytest.mark.parametrize("compaction", ["geometric", "twophase"])
+def test_sketched_ladder_keeps_node_ids(compaction):
+    g, _, _ = _chung_lu(7)
+    res = Solver().solve(g, _sketch_problem(256, compaction=compaction))
+    segs = res.extras["compaction"]["segments"]
+    assert len(segs) > 1
+    assert all(s["n_buf"] == N_NODES for s in segs)
+    assert all(b["m_buf"] < a["m_buf"] for a, b in zip(segs, segs[1:]))
+    assert res.extras["compaction"]["edge_slots_scanned"] == \
+        sum(s["passes"] * s["m_buf"] for s in segs)
+
+
+@pytest.mark.parametrize("compaction", ["geometric", "twophase"])
+def test_sketch_on_the_mesh_keeps_its_ladder_off(compaction):
+    """The mesh ladders renumber node ids, so a sketch there stays off."""
+    prob = Problem.undirected(backend="sketch", substrate="mesh")
+    assert prob.resolve(100).compaction == "off"
+    with pytest.raises(ValueError, match="only the jit ladder keeps them"):
+        Problem.undirected(
+            backend="sketch", substrate="mesh", compaction=compaction
+        ).resolve(100)
 
 
 def test_reference_hashes_are_the_programs():

@@ -111,6 +111,44 @@ def test_chunked_compaction_is_the_plain_compaction(case, chunks):
             assert a == b, name
 
 
+def _reference_keeping_ids(src, dst, w, msk, s_al, t_al, id_map):
+    """The edges-only compaction as one numpy statement."""
+    surv = s_al if t_al is None else s_al | t_al
+    ta = s_al if t_al is None else t_al
+    ok = msk & s_al[src] & ta[dst]
+    e_alive, n_alive = int(ok.sum()), int(surv.sum())
+    new_m = pow2_bucket(max(e_alive, 1), constants.COMPACT_MIN_EDGES)
+    if new_m >= len(src):
+        return None
+    keep = np.nonzero(ok)[0]
+    out = [np.zeros(new_m, a.dtype) for a in (src, dst, w)]
+    for o, a in zip(out, (src, dst, w)):
+        o[: len(keep)] = a[keep]
+    new_msk = np.zeros(new_m, bool)
+    new_msk[: len(keep)] = True
+    return (*out, new_msk, s_al, t_al, id_map, e_alive, n_alive)
+
+
+@pytest.mark.parametrize("chunks", CHUNKS)
+@pytest.mark.parametrize("case", CASES)
+def test_compaction_keeping_ids_gathers_edges_only(case, chunks):
+    """Endpoints keep their ids and the node arrays come back as they are;
+    a rung whose edges fill the next bucket (``all_edges`` too, where only
+    nodes would shrink) is the floor."""
+    rung = _rung(case)
+    want = _reference_keeping_ids(*rung)
+    got = api._compact_rung(*rung, chunks=chunks, keep_ids=True)
+    if case in ("floor", "all_edges"):
+        assert want is None and got is None
+        return
+    assert got is not None
+    for name, a, b in zip(api._Compacted._fields, got, want):
+        if isinstance(b, np.ndarray):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), name
+        else:
+            assert a == b, name
+
+
 @pytest.mark.parametrize("m_buf, parallel", [
     (constants.COMPACT_PARALLEL_MIN_EDGES - 1, False),
     (constants.COMPACT_PARALLEL_MIN_EDGES, True),
