@@ -57,8 +57,8 @@ def main(argv=None) -> int:
     m = int(edges.num_real_edges())
     # compaction pinned off: this bench tracks the ONE-program cache path
     # (cold vs cached latency, retrace counts); the ladder default would
-    # add per-rung programs.  The ladder has its own tracked baseline in
-    # bench_peel_compaction.py.
+    # add per-rung programs.  The ladder is measured on the chip by the
+    # benchmark's lj_peel cell (perfbench/).
     prob = Problem.undirected(eps=args.eps, max_passes=args.max_passes,
                               compaction="off")
     report = {

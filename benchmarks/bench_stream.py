@@ -55,9 +55,9 @@ def _run(make_drv, repeats: int):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    # Defaults reproduce the COMMITTED baseline (like bench_peel_compaction):
-    # running with no flags must regenerate a comparable BENCH_stream.json,
-    # never silently overwrite it with a different configuration.
+    # Defaults reproduce the COMMITTED baseline: running with no flags must
+    # regenerate a comparable BENCH_stream.json, never silently overwrite
+    # it with a different configuration.
     ap.add_argument("--n", type=int, default=200_000)
     ap.add_argument("--avg-deg", type=float, default=8.0)
     ap.add_argument("--exponent", type=float, default=2.0)

@@ -21,13 +21,34 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (
+    Problem,
+    c_grid,
     charikar_greedy,
-    densest_directed_search,
-    densest_subgraph,
     densest_subgraph_exact,
-    densest_subgraph_sketched,
+    solve,
 )
 from repro.graph import generators as gen
+
+
+def _peel(edges, eps, track_history=True, **kw):
+    """Algorithm 1 on the uncompacted loop (per-pass history kept)."""
+    return solve(
+        edges,
+        Problem.undirected(
+            eps=eps, track_history=track_history, compaction="off", **kw
+        ),
+    )
+
+
+def _directed_search(edges, eps, delta):
+    """Algorithm 3 over the geometric c grid: (result, best c, per-c
+    densities, per-c passes)."""
+    res = solve(
+        edges,
+        Problem.directed(c=None, eps=eps, c_delta=delta, compaction="off"),
+    )
+    ex = res.extras
+    return res, ex["best_c"], np.asarray(ex["c_density"]), np.asarray(ex["c_passes"])
 
 
 def _rows_to_csv(rows: List[Dict[str, Any]]) -> str:
@@ -65,7 +86,7 @@ def table2(eps_list=(0.001, 0.1, 1.0)) -> List[Dict[str, Any]]:
             "charikar_ratio": round(rho_star / max(rho_greedy, 1e-9), 4),
         }
         for eps in eps_list:
-            res = densest_subgraph(edges, eps=eps, track_history=False)
+            res = _peel(edges, eps, track_history=False)
             ratio = rho_star / max(float(res.best_density), 1e-9)
             row[f"ratio_eps{eps}"] = round(ratio, 4)
             row[f"passes_eps{eps}"] = int(res.passes)
@@ -85,7 +106,7 @@ def fig61(eps_list=(0.001, 0.01, 0.1, 0.5, 1.0, 2.0, 4.0)) -> List[Dict[str, Any
     rows = []
     for eps in eps_list:
         t0 = time.time()
-        res = densest_subgraph(edges, eps=eps, track_history=False)
+        res = _peel(edges, eps, track_history=False)
         jax.block_until_ready(res.best_density)
         rho = float(res.best_density)
         if base is None:
@@ -109,7 +130,7 @@ def fig61(eps_list=(0.001, 0.01, 0.1, 0.5, 1.0, 2.0, 4.0)) -> List[Dict[str, Any
 
 def fig62_63(eps=0.5) -> List[Dict[str, Any]]:
     edges = gen.chung_lu_power_law(n=100_000, exponent=2.0, avg_deg=10.0, seed=8)
-    res = densest_subgraph(edges, eps=eps, track_history=True)
+    res = _peel(edges, eps)
     rows = []
     hn = np.asarray(res.history_n)
     hm = np.asarray(res.history_m)
@@ -142,8 +163,8 @@ def table3() -> List[Dict[str, Any]]:
     rows = []
     for eps in (0.0, 1.0, 2.0):
         for delta in (2.0, 10.0, 100.0):
-            best, best_c, rhos, passes = densest_directed_search(
-                edges, eps=max(eps, 1e-9), delta=delta
+            best, best_c, rhos, passes = _directed_search(
+                edges, max(eps, 1e-9), delta
             )
             rows.append(
                 {
@@ -158,12 +179,8 @@ def table3() -> List[Dict[str, Any]]:
 
 
 def fig64_66(eps=1.0, delta=2.0) -> List[Dict[str, Any]]:
-    from repro.core.peel_directed import c_grid
-
     edges = _directed_graph()
-    best, best_c, rhos, passes = densest_directed_search(
-        edges, eps=eps, delta=delta
-    )
+    best, best_c, rhos, passes = _directed_search(edges, eps, delta)
     rows = []
     for c, rho, p in zip(c_grid(edges.n_nodes, delta), rhos, passes):
         rows.append(
@@ -184,13 +201,18 @@ def table4(t: int = 5) -> List[Dict[str, Any]]:
     for eps in (0.0, 0.5, 1.0, 1.5, 2.0):
         # eps=0 row: threshold exactly 2*rho (paper's Table 4 top row);
         # cap passes so the while_loop bound stays sane.
-        exact = densest_subgraph(
-            edges, eps=max(eps, 1e-9), max_passes=256, track_history=False
+        exact = _peel(
+            edges, max(eps, 1e-9), max_passes=256, track_history=False
         )
         row = {"eps": eps, "rho_exact_counts": round(float(exact.best_density), 3)}
         for b in (3000, 4000, 5000):
-            sk = densest_subgraph_sketched(
-                edges, eps=max(eps, 1e-9), t=t, b=b, seed=11, max_passes=256
+            sk = solve(
+                edges,
+                Problem.undirected(
+                    eps=max(eps, 1e-9), max_passes=256, track_history=True,
+                    backend="sketch", sketch_tables=t, sketch_buckets=b,
+                    sketch_seed=11,
+                ),
             )
             row[f"ratio_b{b}"] = round(
                 float(sk.best_density) / max(float(exact.best_density), 1e-9), 4
@@ -214,15 +236,20 @@ def fig67() -> List[Dict[str, Any]]:
     collectives are real; one device runs the same program unsharded."""
     from jax.sharding import Mesh
 
-    from repro.core.mapreduce import densest_subgraph_distributed
-
     n_dev = jax.device_count()
     mesh = Mesh(np.asarray(jax.devices()).reshape(n_dev), ("data",))
     rows = []
     for n, avg in ((50_000, 8.0), (200_000, 10.0), (500_000, 12.0)):
         edges = gen.chung_lu_power_law(n=n, exponent=2.0, avg_deg=avg, seed=12)
         t0 = time.time()
-        res = densest_subgraph_distributed(edges, mesh, ("data",), eps=0.5)
+        res = solve(
+            edges,
+            Problem.undirected(
+                eps=0.5, substrate="mesh", edge_axes=("data",),
+                compaction="off",
+            ),
+            mesh=mesh,
+        )
         jax.block_until_ready(res.best_density)
         wall = time.time() - t0
         passes = int(res.passes)
@@ -284,7 +311,7 @@ def lemma5(k_values=(4, 5, 6, 7)) -> List[Dict[str, Any]]:
     rows = []
     for k in k_values:
         edges = gen.lemma5_instance(k)
-        res = densest_subgraph(edges, eps=0.05, track_history=False)
+        res = _peel(edges, 0.05, track_history=False)
         rows.append(
             {
                 "k": k,

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import densest_subgraph_sets
+from repro.core import Problem, solve
 from repro.graph.edgelist import from_numpy
 from repro.graph.generators import planted_partition
 from repro.graph.sampler import CSRGraph, LayeredSampler
@@ -33,7 +33,10 @@ def peel_communities(edges, k_communities: int, eps: float = 0.5):
     for _ in range(k_communities):
         keep = ~(removed[src] | removed[dst])
         sub = from_numpy(src[keep], dst[keep], n)
-        nodes, rho = densest_subgraph_sets(sub, eps=eps)
+        res = solve(
+            sub, Problem.undirected(eps=eps, track_history=True, compaction="off")
+        )
+        nodes, rho = res.nodes(), float(res.best_density)
         nodes = np.asarray([u for u in nodes if not removed[u]])
         if len(nodes) == 0:
             break

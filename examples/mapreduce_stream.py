@@ -15,8 +15,9 @@ A power-law graph (2M nodes / ~8M edges by default) is processed four ways:
   3. MAPREDUCE-ANALOGUE (paper §5.2): the whole O(log n)-pass algorithm as
      ONE compiled XLA program over an edge-sharded device mesh (this process
      forces 8 host devices to make the collectives real).
-  4. TWO-PHASE COMPACTED peel (beyond-paper): same answer, provably smaller
-     phase-2 psums via Lemma 4; plus the Count-Sketch memory mode (§5.1).
+  4. COMPACTION LADDER on the mesh (beyond-paper): same answer, with the
+     Lemma-4 bucket ladder shrinking the sharded edge buffers rung by rung
+     inside one compiled program; plus the Count-Sketch memory mode (§5.1).
 
 All exact modes must agree (and the Count-Sketch variant within its
 approximation).
@@ -33,14 +34,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.core import (
-    Problem,
-    StreamingDensest,
-    chunked_from_arrays,
-    chunked_from_memmap,
-    solve,
-)
-from repro.core.mapreduce import make_distributed_peel_twophase, shard_edges
+from repro.core import Problem, StreamingDensest, solve
+from repro.core.streaming import chunked_from_arrays, chunked_from_memmap
 from repro.graph.edgelist import save_edges_memmap
 from repro.graph.generators import chung_lu_power_law
 
@@ -120,7 +115,11 @@ def main(argv=None):
     n_dev = jax.device_count()
     mesh = Mesh(np.asarray(jax.devices()).reshape(n_dev), ("data",))
     t0 = time.time()
-    res = solve(edges, Problem.undirected(eps=0.5, substrate="mesh"), mesh=mesh)
+    res = solve(
+        edges,
+        Problem.undirected(eps=0.5, substrate="mesh", compaction="off"),
+        mesh=mesh,
+    )
     jax.block_until_ready(res.best_density)
     rho_dist = float(res.best_density)
     print(
@@ -128,17 +127,18 @@ def main(argv=None):
         f"wall={time.time() - t0:.1f}s (one compiled while_loop)"
     )
 
-    # ---- 3. two-phase compacted peel (beyond-paper) -----------------------
-    sh = shard_edges(edges, mesh, ("data",))
-    two = make_distributed_peel_twophase(
-        mesh, ("data",), eps=0.5, n_nodes=sh.n_nodes, phase1_passes=6
-    )
+    # ---- 3. the compaction ladder as one mesh program (beyond-paper) ------
     t0 = time.time()
-    r2 = two(sh.src, sh.dst, sh.weight, sh.mask)
+    r2 = solve(
+        edges,
+        Problem.undirected(eps=0.5, substrate="mesh", compaction="geometric"),
+        mesh=mesh,
+    )
     jax.block_until_ready(r2.best_density)
+    rungs = len(r2.extras["compaction"]["segments"])
     print(
-        f"[two-phase]  rho={float(r2.best_density):.4f} passes={int(r2.passes)} "
-        f"wall={time.time() - t0:.1f}s (phase-2 ids compacted)"
+        f"[mesh ladder] rho={float(r2.best_density):.4f} passes={int(r2.passes)} "
+        f"wall={time.time() - t0:.1f}s ({rungs} rungs, one compiled program)"
     )
 
     # ---- 4. Count-Sketch memory mode (paper §5.1) -------------------------

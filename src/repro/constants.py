@@ -46,10 +46,9 @@ COMPACT_MAX_WORKERS = 16
 COMPACT_CHUNKS_PER_WORKER = 4
 
 # --- single-program mesh ladder (core/api.py, §5.2) -------------------------
-# Rung capacities shrink by this factor.  4 is the measured sweet spot on
-# the tracked benchmark — halving rungs doubles the compaction-collective
-# count for edge-slot savings the pass cost no longer dominates
-# (benchmarks/bench_peel_compaction.py).
+# Rung capacities shrink by this factor.  4 was the measured sweet spot on
+# CPU — halving rungs doubles the compaction-collective count for
+# edge-slot savings the pass cost no longer dominates.
 LADDER_STRIDE = 4
 # Bucket floor: below this many (global) edge slots a pass is trivial, but
 # every extra rung still pays its fixed while-loop/compaction cost inside

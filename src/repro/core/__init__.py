@@ -7,28 +7,28 @@ programs so production request rates never retrace; ``solve_batch`` runs
 multi-eps / multi-c / stacked-graph sweeps as one XLA program.
 
     from repro.core import Problem, solve
-    res = solve(edges, Problem.undirected(eps=0.5))
-    res = solve(edges, Problem.directed())            # c-grid search
-    res = solve(edges, Problem.at_least_k(k=100))
+    res = solve(edges, Problem.undirected(eps=0.5))   # Algorithm 1
+    res = solve(edges, Problem.at_least_k(k=100))     # Algorithm 2
+    res = solve(edges, Problem.directed())            # Algorithm 3, c-grid
 
 All peel variants are one engine (core/engine.py): a single pass body
 parameterized by RemovalPolicy × DegreeBackend, launched on a jit, host
-streaming, or shard_map substrate.  The historical entry points below are
-thin delegations through the same lowering and stay bit-identical:
+streaming, or shard_map substrate.  This package exports:
 
-  densest_subgraph                 Algorithm 1 (undirected, (2+2eps)-approx)
-  densest_subgraph_at_least_k      Algorithm 2 (size >= k, (3+3eps)-approx)
-  densest_subgraph_directed        Algorithm 3 (directed, per-c)
-  densest_directed_search          Algorithm 3 + geometric c grid
-  densest_subgraph_sketched        Algorithm 1 with Count-Sketch degrees
-  densest_subgraph_distributed     MapReduce analogue on a device mesh
+  Problem / Solver / solve / solve_batch   the front door (core/api.py)
+  run_peel / PeelOutcome, the policies     the engine itself
+    and the degree backends
   StreamingDensest                 semi-streaming driver w/ checkpoint+stragglers
-  TurnstileDensest/TurnstileSketch ℓ0-sketch dynamic-stream runtime (±edges)
+  TurnstileDensest                 ℓ0-sketch dynamic-stream runtime (±edges)
   LocalExplorer                    Andersen pruned-frontier exploration
                                    (substrate='local', per-seed queries)
   densest_subgraph_exact           Goldberg max-flow exact oracle
+  densest_subgraph_brute /         brute-force oracles for small graphs
+    densest_directed_brute
   charikar_greedy                  node-at-a-time 2-approx baseline [10]
-  run_peel / PeelOutcome           the engine itself (policies × backends)
+
+Everything else lives in its module (``repro.core.countsketch``,
+``repro.core.mapreduce``, ``repro.core.streaming``, ...).
 """
 
 from repro.core.api import (
@@ -36,23 +36,14 @@ from repro.core.api import (
     Problem,
     Provenance,
     Solver,
+    c_grid,
     default_solver,
-    deprecated_alias_getattr,
     solve,
     solve_batch,
     stack_graphs,
 )
 from repro.core.charikar import charikar_greedy
-from repro.core.countsketch import (
-    SketchBackend,
-    densest_subgraph_sketched,
-    make_sketch_params,
-    query_degrees,
-    sketch_degrees_from_edges,
-    sketch_endpoint_counters,
-    sketched_degree_fn,
-)
-from repro.core.density import density_of, max_passes_bound, undirected_stats
+from repro.core.countsketch import SketchBackend
 from repro.core.engine import (
     AtLeastKFraction,
     DirectedST,
@@ -72,40 +63,9 @@ from repro.core.exact import (
     densest_subgraph_brute,
     densest_subgraph_exact,
 )
-from repro.core.local import LocalExploration, LocalExplorer
-from repro.core.mapreduce import (
-    densest_subgraph_distributed,
-    make_distributed_directed_peel,
-    make_distributed_peel,
-    make_distributed_peel_compacted,
-    make_distributed_peel_ladder,
-    shard_edges,
-)
-from repro.core.peel import densest_subgraph, densest_subgraph_sets
-from repro.core.peel_directed import (
-    c_grid,
-    densest_directed_search,
-    densest_directed_search_vmapped,
-    densest_subgraph_directed,
-)
-from repro.core.peel_topk import densest_subgraph_at_least_k
-from repro.core.streaming import (
-    StreamingDensest,
-    chunked_from_arrays,
-    chunked_from_memmap,
-)
-from repro.core.turnstile import TurnstileDensest, TurnstileSketch
-
-# Deprecated result-type aliases (kept importable; warn on access).
-__getattr__ = deprecated_alias_getattr(
-    __name__,
-    {
-        "PeelResult": DenseSubgraphResult,
-        "PeelTopKResult": DenseSubgraphResult,
-        "DirectedPeelResult": DenseSubgraphResult,
-    },
-)
-
+from repro.core.local import LocalExplorer
+from repro.core.streaming import StreamingDensest
+from repro.core.turnstile import TurnstileDensest
 
 __all__ = [
     "AtLeastKFraction",
@@ -113,55 +73,28 @@ __all__ = [
     "DirectedST",
     "ExactBackend",
     "FnBackend",
-    "LocalExploration",
     "LocalExplorer",
     "MeshSegmentSumBackend",
     "PeelOutcome",
-    "PeelResult",  # deprecated alias of DenseSubgraphResult
     "PeelState",
-    "PeelTopKResult",  # deprecated alias of DenseSubgraphResult
     "Problem",
     "Provenance",
     "SketchBackend",
     "Solver",
     "StreamingDensest",
     "TurnstileDensest",
-    "TurnstileSketch",
     "UndirectedThreshold",
     "c_grid",
     "charikar_greedy",
-    "chunked_from_arrays",
-    "chunked_from_memmap",
     "default_solver",
     "densest_directed_brute",
-    "densest_directed_search",
-    "densest_directed_search_vmapped",
-    "densest_subgraph",
-    "densest_subgraph_at_least_k",
     "densest_subgraph_brute",
-    "densest_subgraph_directed",
-    "densest_subgraph_distributed",
     "densest_subgraph_exact",
-    "densest_subgraph_sets",
-    "densest_subgraph_sketched",
-    "density_of",
-    "make_distributed_directed_peel",
-    "make_distributed_peel",
-    "make_distributed_peel_compacted",
-    "make_distributed_peel_ladder",
-    "make_sketch_params",
-    "max_passes_bound",
-    "query_degrees",
     "removal_threshold",
     "run_peel",
     "segment_degree_count",
-    "shard_edges",
-    "sketch_degrees_from_edges",
-    "sketch_endpoint_counters",
-    "sketched_degree_fn",
     "solve",
     "solve_batch",
     "stack_graphs",
     "undirected_pass_step",
-    "undirected_stats",
 ]

@@ -11,7 +11,7 @@ The public surface is three names:
     it.  A Solver memoizes the jitted programs keyed on the Problem's static
     fields plus ``(n_nodes, padded m, dtype)`` so repeated calls at
     production request rates never retrace; :data:`default_solver` backs the
-    module-level helpers and every legacy wrapper.
+    module-level helpers.
   * :func:`solve_batch` — the ROADMAP's batched driver: multi-eps, multi-c
     and stacked same-shape-graph sweeps as ONE vmapped XLA program (the
     engine is vmap-clean; the directed c-grid proved it).
@@ -19,8 +19,7 @@ The public surface is three names:
 Every result is a :class:`DenseSubgraphResult`: the engine's
 :class:`~repro.core.engine.PeelOutcome` arrays plus a static
 :class:`Provenance` recording which cell of the policy × backend × substrate
-matrix actually ran.  The historical ``PeelResult`` / ``PeelTopKResult`` /
-``DirectedPeelResult`` names are deprecated aliases of it.
+matrix actually ran.
 
 Lowering map (Problem field -> engine axis)::
 
@@ -30,17 +29,11 @@ Lowering map (Problem field -> engine axis)::
     backend    exact        -> ExactBackend (segment_sum)
                sketch       -> SketchBackend / _MeshSketchBackend (§5.1)
                pallas       -> tiled-degree kernel via FnBackend  (kernels/)
-    substrate  jit          -> jax.jit(run_peel)                  (peel*.py)
+    substrate  jit          -> jax.jit(run_peel)
                mesh         -> shard_map + psum backends          (§5.2)
                streaming    -> StreamingDensest chunked driver    (§4, semi-streaming)
-    compaction geometric    -> Solver._run_compacted ladder       (amortized O(m))
-               twophase     -> same ladder, one fixed compaction  (legacy schedule)
-
-The legacy entry points (``densest_subgraph``, ``densest_subgraph_at_least_k``,
-``densest_subgraph_directed``, ``densest_directed_search``,
-``densest_subgraph_sketched``, ``densest_subgraph_distributed``,
-``StreamingDensest``) are thin delegations through this module's lowering
-and stay bit-identical to their pre-redesign outputs.
+    compaction geometric    -> Solver._run_compacted host ladder  (jit; amortized O(m))
+                               or one single-program ladder       (mesh)
 """
 
 from __future__ import annotations
@@ -81,7 +74,6 @@ __all__ = [
     "Provenance",
     "Solver",
     "default_solver",
-    "deprecated_alias_getattr",
     "run_cell",
     "solve",
     "solve_batch",
@@ -91,7 +83,7 @@ __all__ = [
 _OBJECTIVES = ("undirected", "at_least_k", "directed")
 _BACKENDS = ("exact", "sketch", "pallas", "auto")
 _SUBSTRATES = ("jit", "mesh", "streaming", "local", "auto")
-_COMPACTIONS = ("off", "twophase", "geometric", "auto")
+_COMPACTIONS = ("off", "geometric", "auto")
 _STREAM_MODES = ("insert", "turnstile")
 
 # Above this node count, "auto" trades the O(n) exact degree vector for the
@@ -193,7 +185,7 @@ class Problem:
     * ``spill_dir`` — sends the streaming ladder's rebuilt survivor
       streams to disk-backed memmaps (atomic manifest, resume re-enters
       mid-rung).  Needs the geometric ladder: rejected on the streaming
-      substrate with an explicit ``compaction='off'``/``'twophase'``.
+      substrate with an explicit ``compaction='off'``.
     * ``residency_cap_edges`` — errors a too-big IN-RAM streaming rebuild
       instead of spiking memory (the spilled path is exempt — that is its
       point); pair it with ``spill_dir`` to make the cap recoverable.
@@ -267,21 +259,21 @@ class Problem:
     * ``compaction`` — ``'off'``: classic single-segment loop;
       ``'geometric'``: the amortized-O(m) ladder — run in segments, gather
       survivors into the next power-of-two bucket when the alive edge
-      count falls below the trigger (on the mesh substrate the WHOLE
-      ladder is one compiled collective-only program); ``'twophase'``:
-      exactly one compaction after ``twophase_passes`` passes (the
-      historical ``make_distributed_peel_twophase`` schedule); ``'auto'``
-      (DEFAULT): geometric, but off for sketch on the mesh substrate.
+      count falls below the trigger (on the jit substrate a host ladder
+      around cached per-bucket programs, :meth:`Solver._run_compacted`; on
+      the mesh substrate the WHOLE ladder is one compiled collective-only
+      program); ``'auto'`` (DEFAULT): geometric, but off for sketch on
+      the mesh substrate.
       Compaction is pure renumbering: results are bit-identical to
       ``'off'`` for integer-valued edge weights (e.g. unweighted graphs).
       A sketched ladder (jit substrate only) keeps node ids and compacts
       edges only, since Count-Sketch estimates hash node ids; the mesh
-      ladders renumber, so mesh × sketch needs ``'off'``.
+      ladder counts exact degrees only, so mesh × sketch needs ``'off'``.
       See docs/compaction.md.
-    * ``twophase_passes`` — twophase phase-1 pass budget.
     * ``min_deg_fallback`` / ``ceil_count`` — Alg 2 realization variants
-      (floor+fallback = single-device legacy, ceil without = distributed
-      legacy); cache-key-exempt for other objectives.
+      (floor+fallback is the default; ceil without fallback removes
+      ``ceil(eps/(1+eps)·|S|)`` per pass); cache-key-exempt for other
+      objectives.
     """
 
     objective: str = "undirected"
@@ -296,10 +288,8 @@ class Problem:
     # Compaction runtime (scheduling; never keys compiled programs).  The
     # default is 'auto' (ROADMAP soak item, flipped after PRs 3-4): every
     # backend rides the geometric ladder by default but mesh × sketch.
-    compaction: str = "auto"  # off | twophase | geometric | auto
-    twophase_passes: int = 8  # compaction='twophase': phase-1 pass budget
-    # Algorithm 2 realization knobs (floor+fallback = single-device legacy,
-    # ceil w/o fallback = distributed legacy).
+    compaction: str = "auto"  # off | geometric | auto
+    # Algorithm 2 realization knobs (floor+fallback by default).
     min_deg_fallback: bool = True
     ceil_count: bool = False
     # Count-Sketch (§5.1) parameters.
@@ -356,10 +346,6 @@ class Problem:
         if self.compaction not in _COMPACTIONS:
             raise ValueError(
                 f"compaction={self.compaction!r} not in {_COMPACTIONS}"
-            )
-        if self.twophase_passes < 1:
-            raise ValueError(
-                f"twophase_passes={self.twophase_passes} must be >= 1"
             )
         if self.objective == "at_least_k" and (self.k is None or self.k < 1):
             raise ValueError("objective='at_least_k' needs k >= 1")
@@ -479,7 +465,7 @@ class Problem:
             # node state, out-of-core edges): its only cell is exact.
             if substrate == "streaming":
                 backend = "exact"
-            elif self.compaction in ("geometric", "twophase"):
+            elif self.compaction == "geometric":
                 # An explicit compaction request steers the resolution to
                 # the exact backend (answers users already get; the
                 # sketch rides only the jit ladder, with node ids kept).
@@ -488,8 +474,8 @@ class Problem:
                 backend = "sketch" if n_nodes > _AUTO_SKETCH_NODES else "exact"
         compaction = self.compaction
         # Count-Sketch estimates hash node ids.  The jit ladder keeps them
-        # for a sketched solve and compacts edges only; the mesh ladders
-        # renumber, which would change the estimates.
+        # for a sketched solve and compacts edges only; the mesh ladder
+        # counts exact degrees only.
         ladder_keeps_ids = substrate == "jit"
         if compaction == "auto":
             compaction = (
@@ -510,11 +496,6 @@ class Problem:
                 "Count-Sketch degree estimates hash node ids, and only the jit "
                 "ladder keeps them; backend='sketch' with "
                 f"substrate={p.substrate!r} needs compaction='off'"
-            )
-        if p.compaction == "twophase" and p.substrate == "streaming":
-            raise ValueError(
-                "the streaming driver compacts geometrically; use "
-                "compaction='geometric' or 'off' with substrate='streaming'"
             )
         if (
             p.spill_dir is not None
@@ -593,7 +574,6 @@ _FIELD_CLASS = {
     "wire_dtype": "conditional",
     "c_delta": "exempt",
     "compaction": "exempt",
-    "twophase_passes": "exempt",
     "stream_chunk": "exempt",
     "stream_workers": "exempt",
     "stream_prefetch": "exempt",
@@ -632,14 +612,13 @@ class Provenance:
     max_passes: int
     batch: Optional[str] = None  # None | "eps" | "c" | "graphs"
     cache_hit: bool = False
-    compaction: str = "off"  # off | twophase | geometric (resolved)
+    compaction: str = "off"  # off | geometric (resolved)
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class DenseSubgraphResult:
-    """The one result type of the front door (and the deprecation target of
-    ``PeelResult`` / ``PeelTopKResult`` / ``DirectedPeelResult``).
+    """The one result type of the front door.
 
     Field-compatible with :class:`~repro.core.engine.PeelOutcome`; batched
     solves carry a leading sweep axis on every array.  ``extras`` holds
@@ -729,14 +708,11 @@ def _backend_for(
     problem: Problem,
     n_nodes: int,
     *,
-    degree_fn: Optional[Callable] = None,
     tiling: Optional[Tuple[jax.Array, jax.Array]] = None,
 ):
-    """Problem -> DegreeBackend (jit substrate).  ``degree_fn`` is the
-    legacy hook escape hatch; ``tiling`` carries the Pallas bucketing arrays
-    as runtime values so compiled programs stay graph-independent."""
-    if degree_fn is not None:
-        return FnBackend(degree_fn)
+    """Problem -> DegreeBackend (jit substrate).  ``tiling`` carries the
+    Pallas bucketing arrays as runtime values so compiled programs stay
+    graph-independent."""
     if problem.backend == "exact":
         return ExactBackend()
     if problem.backend == "sketch":
@@ -771,7 +747,6 @@ def run_cell(
     *,
     eps: Any = None,
     c: Any = None,
-    degree_fn: Optional[Callable] = None,
     tiling: Optional[Tuple[jax.Array, jax.Array]] = None,
     max_passes: Optional[int] = None,
     init_alive: Optional[jax.Array] = None,
@@ -785,8 +760,7 @@ def run_cell(
     """The pure, traceable lowering core: one Problem cell -> ``run_peel``.
 
     Safe under jit/vmap/shard_map; ``eps`` and ``c`` may be traced scalars.
-    Everything in solve()/solve_batch() and every legacy wrapper bottoms out
-    here (substrates add their own launch wrappers around it).  The
+    Every jit program of solve()/solve_batch() bottoms out here.  The
     ``init_*``/``compact_below`` segment controls are forwarded to
     :func:`~repro.core.engine.run_peel` — ``run_cell`` itself is always ONE
     segment; the host-side compaction ladder around it lives in
@@ -795,7 +769,7 @@ def run_cell(
     prob = problem.resolve(edges.n_nodes)
     mp = max_passes if max_passes is not None else prob.resolved_max_passes(edges.n_nodes)
     policy = _policy_for(prob, eps=eps, c=c)
-    backend = _backend_for(prob, edges.n_nodes, degree_fn=degree_fn, tiling=tiling)
+    backend = _backend_for(prob, edges.n_nodes, tiling=tiling)
     return run_peel(
         edges, policy, backend, mp, track_history=prob.track_history,
         init_alive=init_alive, init_t_alive=init_t_alive, init_t=init_t,
@@ -835,36 +809,13 @@ def stack_graphs(graphs: Sequence[EdgeList]) -> EdgeList:
     )
 
 
-def deprecated_alias_getattr(module_name: str, aliases: Dict[str, Any]):
-    """Builds a module ``__getattr__`` that serves deprecated names with a
-    DeprecationWarning (the PeelResult-family shims share this one body)."""
-
-    def __getattr__(name: str):
-        target = aliases.get(name)
-        if target is not None:
-            import warnings
-
-            warnings.warn(
-                f"{module_name}.{name} is deprecated; use "
-                "repro.core.DenseSubgraphResult",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            return target
-        raise AttributeError(f"module {module_name!r} has no attribute {name!r}")
-
-    return __getattr__
-
-
 def _tiling_arrays(edges: EdgeList, problem: Problem, pow2_pad: bool = False):
     """Host-side Pallas tile bucketing for this graph (runtime args of the
     cached program, so the compiled code is reusable across graphs).
 
     This is an O(E) numpy pass per call — the compiled program is cached but
     the bucketing is not (it depends on edge CONTENT, which a shape-keyed
-    cache cannot see).  For request-rate serving of one graph, bucket once
-    and pass ``degree_fn=degree_fn_from_tiling(tiled)`` instead: the hook
-    keys the program cache by identity and skips the per-call rebuild.
+    cache cannot see).
 
     ``pow2_pad`` rounds the per-tile edge capacity up to a power of two so
     the compaction ladder's re-bucketed tilings land on a bounded set of
@@ -1119,7 +1070,7 @@ class _DiskBackedProgram:
 
 
 # Program kinds eligible for the disk tier: single-device jit programs.
-# Mesh executables (mesh/cseg_mesh/ladder_mesh) embed a device topology and
+# Mesh executables (mesh/ladder_mesh) embed a device topology and
 # their keys hold live Mesh objects — they stay in-memory only.
 _DISK_KINDS = ("solve", "eps", "c", "graphs", "cseg")
 
@@ -1129,7 +1080,7 @@ class Solver:
     requests never retrace.
 
     Cache key: ``(kind, problem static fields, max_passes, n_nodes,
-    padded m, weight dtype, degree_fn, aux shapes | mesh)``.  ``trace_count``
+    padded m, weight dtype, aux shapes | mesh)``.  ``trace_count``
     counts actual retraces (incremented inside the traced Python bodies) and
     ``cache_hits``/``cache_misses`` count program-cache lookups — the
     observability hooks the retrace tests and bench_api use.
@@ -1204,9 +1155,7 @@ class Solver:
         if fn is None:
             self.cache_misses += 1
             fn = build()
-            if disk_dir is not None and key[0] in _DISK_KINDS and key[6] is None:
-                # degree_fn hooks (key[6]) are keyed by object identity,
-                # which no other process can reproduce — memory tier only.
+            if disk_dir is not None and key[0] in _DISK_KINDS:
                 fn = _DiskBackedProgram(self, fn, disk_dir, key)
             self._programs[key] = fn
             if self.max_cached_programs is not None:
@@ -1229,7 +1178,6 @@ class Solver:
         n_nodes: int,
         m_padded: int,
         dtype,
-        degree_fn,
         aux: Tuple = (),
     ) -> Tuple:
         # A field may only be dropped from the key when the program takes it
@@ -1239,12 +1187,11 @@ class Solver:
         # never reads it (no spurious recompiles from irrelevant knobs).
         # The uniform exclusions come from _FIELD_CLASS ('exempt' = host
         # driver/scheduling state: stream_*/spill/cache_dir/turnstile knobs,
-        # and compaction/twophase_passes — segment programs key on (seg
-        # max_passes, compact_below) via mp/aux instead, so geometric and
-        # twophase ladders share bucket programs); max_passes keys through
-        # its resolved value (the mp slot).
+        # and compaction — segment programs key on (seg max_passes,
+        # compact_below) via mp/aux instead); max_passes keys through its
+        # resolved value (the mp slot).
         exclude = {"max_passes"} | _EXEMPT_FIELDS
-        if kind in ("solve", "mesh", "c", "cseg", "cseg_mesh", "ladder_mesh"):
+        if kind in ("solve", "mesh", "c", "cseg", "ladder_mesh"):
             exclude.add("c")  # these programs take c as a runtime argument
         if kind == "eps":
             exclude.add("eps")
@@ -1265,7 +1212,6 @@ class Solver:
             n_nodes,
             m_padded,
             str(dtype),
-            degree_fn,
             aux,
         )
 
@@ -1275,7 +1221,6 @@ class Solver:
         problem: Problem,
         mp: int,
         kind: str,
-        degree_fn: Optional[Callable],
         with_tiling: bool,
     ) -> Callable:
         solver = self
@@ -1283,8 +1228,7 @@ class Solver:
 
         def cell(edges, *, eps=None, c=None, tiling=None):
             return run_cell(
-                edges, problem, eps=eps, c=c, degree_fn=degree_fn,
-                tiling=tiling, max_passes=mp,
+                edges, problem, eps=eps, c=c, tiling=tiling, max_passes=mp,
             )
 
         if kind == "solve":
@@ -1370,16 +1314,9 @@ class Solver:
         mp: int,
         mesh,
         n_nodes: int,
-        segment: bool = False,
-        compact_below: Optional[int] = None,
     ) -> Callable:
         """shard_map substrate (§5.2): edges sharded over ``edge_axes``,
-        node state replicated, one fused psum per pass.  With ``segment``
-        the program is one rung of the compaction ladder — it takes the
-        replicated carried state (alive bitmap(s), absolute pass counter)
-        and stops at ``compact_below``; the alive-edge trigger count is
-        psummed (``MeshSegmentSumBackend.count_edges``) so all devices
-        agree on the segment boundary."""
+        node state replicated, one fused psum per pass."""
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
@@ -1402,41 +1339,14 @@ class Solver:
         solver = self
         directed = problem.objective == "directed"
 
-        def _local_run(src, dst, weight, mask, c=None, **seg_kw):
+        def _local_run(src, dst, weight, mask, c=None):
             e = EdgeList(src=src, dst=dst, weight=weight, mask=mask, n_nodes=n_nodes)
             policy = _policy_for(problem, c=c)
             return run_peel(
                 e, policy, backend, mp, track_history=problem.track_history,
-                **seg_kw,
             )
 
-        if segment:
-            # ae0 is the replicated host-known entry count; the entry filter
-            # is the (sharded) edge mask itself, so a rung starts without
-            # scanning its shard.
-            seg_static = dict(
-                init_best_empty=True, compact_below=compact_below,
-                init_ok_from_mask=True,
-            )
-            if directed:
-                def local(src, dst, weight, mask, alive0, ta0, t0, ae0, c):
-                    return _local_run(
-                        src, dst, weight, mask, c,
-                        init_alive=alive0, init_t_alive=ta0, init_t=t0,
-                        init_alive_edges=ae0, **seg_static,
-                    )
-
-                in_specs = (P(axes),) * 4 + (P(), P(), P(), P(), P())
-            else:
-                def local(src, dst, weight, mask, alive0, t0, ae0):
-                    return _local_run(
-                        src, dst, weight, mask,
-                        init_alive=alive0, init_t=t0,
-                        init_alive_edges=ae0, **seg_static,
-                    )
-
-                in_specs = (P(axes),) * 4 + (P(), P(), P())
-        elif directed:
+        if directed:
             def local(src, dst, weight, mask, c):
                 return _local_run(src, dst, weight, mask, c)
 
@@ -1460,10 +1370,10 @@ class Solver:
     def _mesh_fn(self, prob: Problem, mesh, n_nodes: int):
         """Cached shard_map program for a RESOLVED problem.  Keyed without
         edge shapes (jit re-keys on shard shapes internally) so
-        ``make_distributed_*`` warming and ``solve(substrate='mesh')``
+        :meth:`mesh_program` warming and ``solve(substrate='mesh')``
         serving share one compilation."""
         mp = prob.resolved_max_passes(n_nodes)
-        key = self._key("mesh", prob, mp, n_nodes, -1, "sharded", None, (mesh,))
+        key = self._key("mesh", prob, mp, n_nodes, -1, "sharded", (mesh,))
         fn, hit = self._get(
             key, lambda: self._build_mesh_program(prob, mp, mesh, n_nodes)
         )
@@ -1473,8 +1383,9 @@ class Solver:
         self, problem: Problem, mesh, n_nodes: int
     ) -> Callable:
         """The cached shard_map program ``fn(src, dst, weight, mask[, c]) ->
-        PeelOutcome`` — the lowering target of the ``make_distributed_*``
-        builders in core/mapreduce.py."""
+        PeelOutcome`` over edge arrays sharded over ``problem.edge_axes``
+        (:func:`~repro.core.mapreduce.shard_edges`) — the uncompacted mesh
+        solve, for callers that shard and launch it themselves."""
         fn, _, _ = self._mesh_fn(problem.resolve(n_nodes), mesh, n_nodes)
         return fn
 
@@ -1491,8 +1402,7 @@ class Solver:
         program (mesh substrate): every rung's peel segment AND the
         compaction between rungs run inside the compiled program, so a
         multi-device run is collective-only end to end — no host
-        gather/reshard per rung (the ``_run_compacted`` schedule's mesh cost
-        this replaces).
+        gather/reshard per rung.
 
         ``schedule`` is the static Lemma-4 bucket ladder
         (:func:`~repro.graph.partition.ladder_schedule`): per-shard edge
@@ -1628,9 +1538,8 @@ class Solver:
         edge slots: ``(fn, schedule, n_shards, hit)`` where ``fn(src, dst,
         weight, mask[, c]) -> (PeelOutcome, rung_t)`` expects the edge
         arrays padded to ``schedule[0] * n_shards`` slots and sharded over
-        ``problem.edge_axes`` — the lowering target of
-        :func:`~repro.core.mapreduce.make_distributed_peel_ladder` and of
-        ``solve()`` for mesh × ``compaction='geometric'``.  The program
+        ``problem.edge_axes`` — the lowering target of ``solve()`` for
+        mesh × ``compaction='geometric'``.  The program
         cache key includes the static bucket schedule; rung 0 is the exact
         shard-rounded input size, so only graphs with the SAME padded edge
         count share a compilation (repeat solves and the whole directed
@@ -1660,8 +1569,7 @@ class Solver:
         schedule += tuple(c for c in tail if c < schedule[-1] and c >= floor)
         mp = prob.resolved_max_passes(n_nodes)
         key = self._key(
-            "ladder_mesh", prob, mp, n_nodes, -1, "sharded", None,
-            (mesh, schedule),
+            "ladder_mesh", prob, mp, n_nodes, -1, "sharded", (mesh, schedule),
         )
         fn, hit = self._get(
             key,
@@ -1674,7 +1582,7 @@ class Solver:
     def _mesh_ladder_runner(
         self, graph: EdgeList, prob: Problem, mesh
     ) -> Callable[[Optional[float]], Tuple[PeelOutcome, Dict[str, Any], bool]]:
-        """``_run_compacted``'s mesh × geometric replacement: pads and
+        """The mesh × geometric runner of :meth:`_solve_compacted`: pads and
         shards the graph ONCE, then returns ``run(c)`` launching the
         single-program ladder (collective-only; zero host gather/reshard
         round-trips between rungs) — the directed c-grid reuses both the
@@ -1732,7 +1640,7 @@ class Solver:
 
         return run
 
-    # -- compaction ladder (geometric | twophase) ---------------------------
+    # -- host compaction ladder (jit substrate) -----------------------------
     def _segment_fn(
         self,
         prob: Problem,
@@ -1742,24 +1650,11 @@ class Solver:
         m_cur: int,
         dtype,
         tiling_shapes: Tuple,
-        mesh,
     ):
-        """Cached program for one ladder rung (jit or mesh substrate)."""
-        if prob.substrate == "mesh":
-            key = self._key(
-                "cseg_mesh", prob, seg_mp, n_cur, -1, "sharded", None,
-                (mesh, compact_below),
-            )
-            return self._get(
-                key,
-                lambda: self._build_mesh_program(
-                    prob, seg_mp, mesh, n_cur,
-                    segment=True, compact_below=compact_below,
-                ),
-            )
+        """Cached program for one ladder rung."""
         with_tiling = prob.backend == "pallas"
         key = self._key(
-            "cseg", prob, seg_mp, n_cur, m_cur, dtype, None,
+            "cseg", prob, seg_mp, n_cur, m_cur, dtype,
             (compact_below,) + tiling_shapes,
         )
         return self._get(
@@ -1771,12 +1666,12 @@ class Solver:
         )
 
     def _run_compacted(
-        self, graph: EdgeList, prob: Problem, mesh, c: Optional[float]
+        self, graph: EdgeList, prob: Problem, c: Optional[float]
     ) -> Tuple[PeelOutcome, Dict[str, Any], bool]:
-        """The geometric-compaction runtime: runs the SAME engine loop in
-        segments, gathering survivors (edges and nodes) into the next
-        power-of-two buffer whenever the alive edge count falls below half
-        the current padded buffer — pass k then scans O(m_k) edge slots
+        """The jit substrate's host compaction ladder: runs the SAME engine
+        loop in segments, gathering survivors (edges and nodes) into the
+        next power-of-two buffer whenever the alive edge count falls below
+        half the current padded buffer — pass k then scans O(m_k) edge slots
         instead of O(m), amortized O(m) total (Lemma 4 drives the geometric
         shrink; cf. the per-round compaction in Mitrović & Pan).
 
@@ -1788,16 +1683,8 @@ class Solver:
         node ids and compact edges only (:func:`_compact_rung`'s
         ``keep_ids``): Count-Sketch hashes see the original ids, so its
         counters, and with them every decision, are those of the
-        uncompacted loop too.  ``compaction='twophase'``
-        reuses the same machinery with a fixed schedule: one compaction
-        after ``twophase_passes`` passes (the historical
-        ``make_distributed_peel_twophase`` recipe).
-
-        On the mesh substrate this host schedule now serves only
-        ``'twophase'``: mesh × ``'geometric'`` lowers onto the
-        single-program collective-only ladder (:meth:`_mesh_ladder_runner`).
-        Calling this directly with mesh × geometric still runs the host
-        gather/reshard ladder — the benchmark's comparison baseline.
+        uncompacted loop too.  The mesh substrate runs its ladder as one
+        program instead (:meth:`_mesh_ladder_runner`).
 
         Returns ``(outcome in the ORIGINAL id space, ladder report, all
         segment programs were cache hits)``.
@@ -1842,20 +1729,15 @@ class Solver:
         cur_alive_edges = int(msk.sum())
         # Count-Sketch degrees hash node ids: its rungs compact edges only.
         keep_ids = prob.backend == "sketch"
-        twophase = prob.compaction == "twophase"
-        # twophase_passes >= 1 is Problem-validated; mp=0 must stay 0 so a
-        # zero-budget run executes no passes, exactly like 'off'.
-        tp_k1 = min(int(prob.twophase_passes), mp)
         no_more_compact = False
         all_hit = True
 
         for seg_idx in range(_COMPACT_MAX_SEGMENTS):
-            seg_mp = tp_k1 if (twophase and seg_idx == 0) else mp
             compact_below = None
             # With node ids kept, a rung at the floor even with no survivor
             # left gets no trigger (no 0-pass round trip).
             no_more_compact |= keep_ids and _at_floor(len(src), n_cur, 0, 0, True)
-            if prob.compaction == "geometric" and not no_more_compact:
+            if not no_more_compact:
                 compact_below = max(len(src) // 2, 1)
             shape = dict(rung=seg_idx, n_buf=n_cur, m_buf=len(src))
 
@@ -1873,10 +1755,10 @@ class Solver:
                     # its tables on the device.
                     io.to_host += src.nbytes + dst.nbytes
                     io.to_device += sum(a.nbytes for a in aux_arrays)
-                # Carried segment state, identical on both substrates (must
-                # track the _build_segment_program/_build_mesh_program
-                # signatures): alive bitmap(s), absolute pass counter, entry
-                # alive-edge count, and the runtime c for directed policies.
+                # Carried segment state (must track the
+                # _build_segment_program signature): alive bitmap(s),
+                # absolute pass counter, entry alive-edge count, and the
+                # runtime c for directed policies.
                 carried: Tuple = (io.up(s_al),)
                 if directed:
                     carried += (io.up(t_al),)
@@ -1886,29 +1768,15 @@ class Solver:
                 )
                 if directed:
                     carried += (io.up(c, jnp.float32),)
-                if prob.substrate == "mesh":
-                    from repro.core.mapreduce import shard_edges
-
-                    sh = shard_edges(edges, mesh, prob.edge_axes)
-                    m_buf = sh.n_edges_padded
-                    if compact_below is not None:
-                        compact_below = max(m_buf // 2, 1)
-                else:
-                    m_buf = edges.n_edges_padded
+                m_buf = edges.n_edges_padded
 
             # ---- run the segment; the pass count is the first sync ----
             with span("densest.ladder.rung", **shape):
-                if prob.substrate == "mesh":
-                    fn, hit = self._segment_fn(
-                        prob, seg_mp, compact_below, n_cur, m_buf, dtype, (), mesh
-                    )
-                    out = fn(sh.src, sh.dst, sh.weight, sh.mask, *carried)
-                else:
-                    fn, hit = self._segment_fn(
-                        prob, seg_mp, compact_below, n_cur, m_buf, dtype,
-                        tuple(a.shape for a in aux_arrays), None,
-                    )
-                    out = fn(edges, *aux_arrays, *carried)
+                fn, hit = self._segment_fn(
+                    prob, mp, compact_below, n_cur, m_buf, dtype,
+                    tuple(a.shape for a in aux_arrays),
+                )
+                out = fn(edges, *aux_arrays, *carried)
                 t_prev = t_done
                 t_done = int(io.down(out.passes))
             all_hit = all_hit and hit
@@ -1998,7 +1866,7 @@ class Solver:
             "passes": int(t_done),
             "single_program": False,
             # Each rung is its own program launch, with a host
-            # gather/relabel (and reshard, on mesh) between rungs.
+            # gather/relabel between rungs.
             "host_round_trips": len(segments),
             "bytes_to_host": io.to_host,
             "bytes_to_device": io.to_device,
@@ -2008,20 +1876,19 @@ class Solver:
     def _solve_compacted(
         self, graph: EdgeList, prob: Problem, mesh
     ) -> DenseSubgraphResult:
-        """solve() tail for ``compaction in ('geometric', 'twophase')`` on
-        the jit/mesh substrates (streaming compacts inside its driver).
-        mesh × geometric lowers onto the SINGLE-PROGRAM ladder
+        """solve() tail for ``compaction='geometric'`` on the jit and mesh
+        substrates (streaming compacts inside its driver), with one runner
+        each: mesh lowers onto the SINGLE-PROGRAM ladder
         (:meth:`_mesh_ladder_runner`, collective-only compaction; the graph
-        is sharded once, reused across the c-grid); everything else runs
-        the host gather/relabel schedule (:meth:`_run_compacted`).
+        is sharded once, reused across the c-grid); jit runs the host
+        gather/relabel ladder (:meth:`_run_compacted`).
         """
-        if prob.substrate == "mesh" and mesh is None:
-            raise ValueError("substrate='mesh' needs solve(..., mesh=Mesh)")
-        if prob.substrate == "mesh" and prob.compaction == "geometric":
-            launch = self._mesh_ladder_runner(graph, prob, mesh)
-            runner = lambda g, p, m, c: launch(c)
+        if prob.substrate == "mesh":
+            if mesh is None:
+                raise ValueError("substrate='mesh' needs solve(..., mesh=Mesh)")
+            runner = self._mesh_ladder_runner(graph, prob, mesh)
         else:
-            runner = self._run_compacted
+            runner = functools.partial(self._run_compacted, graph, prob)
         n = graph.n_nodes
         mp = prob.resolved_max_passes(n)
         if prob.objective == "directed" and prob.c is None:
@@ -2032,7 +1899,7 @@ class Solver:
             rhos, passes = [], []
             all_hit = True
             for cv in grid:
-                out, ladder, hit = runner(graph, prob, mesh, float(cv))
+                out, ladder, hit = runner(float(cv))
                 all_hit = all_hit and hit
                 rho = float(out.best_density)
                 rhos.append(rho)
@@ -2048,7 +1915,7 @@ class Solver:
             }
             return self._wrap(best, prob, n, mp, all_hit, extras=extras)
         c = prob.c if prob.objective == "directed" else None
-        out, ladder, hit = runner(graph, prob, mesh, c)
+        out, ladder, hit = runner(c)
         extras = {"compaction": ladder}
         if prob.backend == "sketch":
             from repro.core.countsketch import SketchCounters
@@ -2091,7 +1958,6 @@ class Solver:
         problem: Problem,
         *,
         mesh=None,
-        degree_fn: Optional[Callable] = None,
         checkpoint_dir: Optional[str] = None,
         resume: bool = False,
         seed: Optional[int] = None,
@@ -2108,8 +1974,7 @@ class Solver:
         ``mesh`` is required for the mesh substrate;
         ``checkpoint_dir``/``resume`` apply to streaming; ``seed`` is
         required by (and only by) ``substrate='local'`` — the node whose
-        dense neighborhood is wanted; ``degree_fn`` is the legacy
-        custom-degree hook (keys the cache by identity).
+        dense neighborhood is wanted.
         Repeated same-shape solves hit the program cache and never retrace
         (``trace_count``/``cache_hits`` are the observability counters).
         """
@@ -2118,16 +1983,6 @@ class Solver:
                 f"solve() takes an EdgeList graph, got {type(graph).__name__}"
             )
         prob = problem.resolve(graph.n_nodes, have_mesh=mesh is not None)
-        if (
-            degree_fn is not None
-            and prob.compaction != "off"
-            and problem.compaction == "auto"
-        ):
-            # Like the mesh sketch downgrade in resolve(): a degree_fn hook
-            # binds one fixed graph, so 'auto' (the default) falls back to
-            # the uncompacted loop instead of erroring — only an EXPLICIT
-            # ladder request conflicts with the hook.
-            prob = dataclasses.replace(prob, compaction="off")
         if prob.substrate != "streaming" and (checkpoint_dir is not None or resume):
             raise ValueError(
                 "checkpoint_dir/resume only apply to substrate='streaming'"
@@ -2138,11 +1993,6 @@ class Solver:
                     "substrate='local' is a host exploration + jit solve; "
                     "a mesh does not apply"
                 )
-            if degree_fn is not None:
-                raise ValueError(
-                    "degree_fn hooks bind one fixed graph; the local "
-                    "candidate subgraph changes per seed"
-                )
             return self._solve_local(graph, prob, seed)
         if seed is not None:
             raise ValueError(
@@ -2150,46 +2000,27 @@ class Solver:
                 f"substrate={prob.substrate!r} solves the whole graph"
             )
         if prob.stream_mode == "turnstile":
-            if degree_fn is not None:
-                raise ValueError(
-                    "degree_fn hooks bind one fixed graph; the turnstile "
-                    "sample changes per query — use backend='exact'|'pallas'"
-                )
             return self._solve_turnstile(graph, prob)
         if prob.substrate == "streaming":
-            if degree_fn is not None:
-                raise ValueError(
-                    "degree_fn hooks only apply to the jit substrate"
-                )
             return self._solve_streaming(graph, prob, checkpoint_dir, resume)
-        if prob.compaction in ("geometric", "twophase"):
-            if degree_fn is not None:
-                raise ValueError(
-                    "degree_fn hooks bind one fixed graph; compaction "
-                    "renumbers buffers per segment — use compaction='off'"
-                )
+        if prob.compaction == "geometric":
             return self._solve_compacted(graph, prob, mesh)
         if prob.substrate == "mesh":
-            if degree_fn is not None:
-                raise ValueError(
-                    "degree_fn hooks only apply to the jit substrate; mesh "
-                    "runs need a psum'ing backend (backend='exact'|'sketch')"
-                )
             return self._solve_mesh(graph, prob, mesh)
 
         n = graph.n_nodes
         mp = prob.resolved_max_passes(n)
-        with_tiling = prob.backend == "pallas" and degree_fn is None
+        with_tiling = prob.backend == "pallas"
         aux: Tuple = ()
         if with_tiling:
             aux = _tiling_arrays(graph, prob)
         key = self._key(
             "solve", prob, mp, n, graph.n_edges_padded,
-            graph.weight.dtype, degree_fn, tuple(a.shape for a in aux),
+            graph.weight.dtype, tuple(a.shape for a in aux),
         )
         fn, hit = self._get(
             key,
-            lambda: self._build_jit_program(prob, mp, "solve", degree_fn, with_tiling),
+            lambda: self._build_jit_program(prob, mp, "solve", with_tiling),
             disk_dir=self._disk_dir(prob),
         )
         if prob.objective == "directed":
@@ -2199,7 +2030,7 @@ class Solver:
         else:
             out = fn(graph, *aux)
         extras = None
-        if prob.backend == "sketch" and degree_fn is None:
+        if prob.backend == "sketch":
             from repro.core.countsketch import SketchCounters
 
             extras = {"sketch": SketchCounters(
@@ -2421,7 +2252,6 @@ class Solver:
         *,
         eps=None,
         c=None,
-        degree_fn: Optional[Callable] = None,
     ) -> DenseSubgraphResult:
         """One XLA program for a whole sweep (ROADMAP batched driver).
 
@@ -2492,11 +2322,11 @@ class Solver:
             mp = prob.resolved_max_passes(batched.n_nodes)
             key = self._key(
                 "graphs", prob, mp, batched.n_nodes, batched.src.shape,
-                batched.weight.dtype, degree_fn,
+                batched.weight.dtype,
             )
             fn, hit = self._get(
                 key,
-                lambda: self._build_jit_program(prob, mp, "graphs", degree_fn, False),
+                lambda: self._build_jit_program(prob, mp, "graphs", False),
                 disk_dir=self._disk_dir(prob),
             )
             out = fn(batched)
@@ -2520,15 +2350,15 @@ class Solver:
                 mp = loosest.resolved_max_passes(n)
             if prob.objective == "directed" and prob.c is None:
                 raise ValueError("eps sweeps over a directed Problem need a fixed c")
-            with_tiling = prob.backend == "pallas" and degree_fn is None
+            with_tiling = prob.backend == "pallas"
             aux: Tuple = _tiling_arrays(graph, prob) if with_tiling else ()
             key = self._key(
                 "eps", prob, mp, n, graph.n_edges_padded,
-                graph.weight.dtype, degree_fn, tuple(a.shape for a in aux),
+                graph.weight.dtype, tuple(a.shape for a in aux),
             )
             fn, hit = self._get(
                 key,
-                lambda: self._build_jit_program(prob, mp, "eps", degree_fn, with_tiling),
+                lambda: self._build_jit_program(prob, mp, "eps", with_tiling),
                 disk_dir=self._disk_dir(prob),
             )
             out = fn(graph, *aux, jnp.asarray(eps_host))
@@ -2540,12 +2370,11 @@ class Solver:
         c_host = np.asarray(c, np.float32).reshape(-1)
         mp = prob.resolved_max_passes(n)
         key = self._key(
-            "c", prob, mp, n, graph.n_edges_padded,
-            graph.weight.dtype, degree_fn,
+            "c", prob, mp, n, graph.n_edges_padded, graph.weight.dtype,
         )
         fn, hit = self._get(
             key,
-            lambda: self._build_jit_program(prob, mp, "c", degree_fn, False),
+            lambda: self._build_jit_program(prob, mp, "c", False),
             disk_dir=self._disk_dir(prob),
         )
         out = fn(graph, jnp.asarray(c_host))
@@ -2561,8 +2390,7 @@ default_solver = Solver()
 
 def solve(graph: EdgeList, problem: Problem, **kw) -> DenseSubgraphResult:
     """``Solver.solve`` on the process-wide :data:`default_solver` (shared
-    compile cache — the production entry point and the target of every
-    legacy wrapper)."""
+    compile cache — the production entry point)."""
     return default_solver.solve(graph, problem, **kw)
 
 

@@ -16,13 +16,12 @@ from __future__ import annotations
 
 import collections.abc
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.api import DenseSubgraphResult, Problem, solve
 from repro.graph.edgelist import EdgeList
 from repro.kernels import hashing
 
@@ -30,12 +29,10 @@ __all__ = [
     "SketchBackend",
     "SketchCounters",
     "SketchParams",
-    "densest_subgraph_sketched",
     "make_sketch_params",
     "query_degrees",
     "sketch_degrees_from_edges",
     "sketch_endpoint_counters",
-    "sketched_degree_fn",
 ]
 
 
@@ -200,39 +197,3 @@ class SketchCounters(collections.abc.Mapping):
     def __len__(self) -> int:
         return len(self.KEYS)
 
-
-def sketched_degree_fn(p: SketchParams):
-    """degree_fn hook for core.peel.densest_subgraph using the sketch."""
-
-    def fn(edges: EdgeList, w_alive: jax.Array) -> jax.Array:
-        counters = sketch_degrees_from_edges(p, edges, w_alive)
-        all_nodes = jnp.arange(edges.n_nodes, dtype=jnp.int32)
-        return query_degrees(p, counters, all_nodes)
-
-    return fn
-
-
-def densest_subgraph_sketched(
-    edges: EdgeList,
-    eps: float = 0.5,
-    t: int = 5,
-    b: int = 1 << 13,
-    seed: int = 0,
-    max_passes: Optional[int] = None,
-) -> DenseSubgraphResult:
-    """Algorithm 1 with Count-Sketch degrees (the Table 4 configuration).
-
-    Thin delegation through the front door: ``Problem(backend='sketch')``
-    lowers onto :class:`SketchBackend`, which is bit-identical to the
-    historical ``degree_fn=sketched_degree_fn(params)`` hook (the engine
-    equivalence tests pin this)."""
-    problem = Problem.undirected(
-        eps=eps,
-        max_passes=max_passes,
-        track_history=True,
-        backend="sketch",
-        sketch_tables=t,
-        sketch_buckets=b,
-        sketch_seed=seed,
-    )
-    return solve(edges, problem)

@@ -31,7 +31,7 @@ under the threshold, and ``init_alive`` / ``init_t_alive`` / ``init_t``
 let the next segment continue the SAME loop (absolute pass counter,
 best-set tracking merged by the caller) on a smaller renumbered buffer.
 The host-side gather/relabel ladder lives in core/api.py
-(``Problem(compaction='geometric'|'twophase')``); compaction is pure
+(``Problem(compaction='geometric')``); compaction is pure
 renumbering, so segmented runs are bit-identical to single-segment runs
 for integer-valued edge weights (and reassociation-level equal otherwise).
 Pass ``k`` then costs ``O(m_k)`` instead of ``O(m)`` — amortized ``O(m)``
@@ -366,18 +366,18 @@ class ExactBackend:
 
 
 class FnBackend:
-    """Adapts a legacy ``degree_fn(edges, w_alive) -> deg[N]`` hook (the
-    Count-Sketch and Pallas degree functions) into a DegreeBackend."""
+    """Adapts an undirected degree function ``fn(edges, w_alive) -> deg[N]``
+    (the Pallas tiled-degree kernel's) into a DegreeBackend."""
 
-    def __init__(self, degree_fn):
-        self.degree_fn = degree_fn
+    def __init__(self, fn):
+        self.fn = fn
 
     def undirected(self, edges, w_alive):
-        return self.degree_fn(edges, w_alive), jnp.sum(w_alive)
+        return self.fn(edges, w_alive), jnp.sum(w_alive)
 
     def directed(self, edges, w_alive):
         raise NotImplementedError(
-            "degree_fn hooks are undirected; use a backend with a directed() rule"
+            "FnBackend degrees are undirected; use a backend with a directed() rule"
         )
 
 

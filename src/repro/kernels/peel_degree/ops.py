@@ -1,6 +1,7 @@
 """jit'd public wrapper: degrees of the current alive subgraph from the
-static tile bucketing.  Drop-in ``degree_fn`` for core/peel.py, so the
-Pallas kernel powers the same Algorithm 1 loop the XLA path uses."""
+static tile bucketing.  Wrapped in an engine ``FnBackend`` (by
+``Problem(backend='pallas')``'s lowering, or :func:`degree_backend_from_tiling`),
+the Pallas kernel powers the same Algorithm 1 loop the XLA path uses."""
 
 from __future__ import annotations
 
@@ -52,7 +53,8 @@ def degree_fn_from_tiling(
     use_pallas: bool = True,
     interpret: Optional[bool] = None,
 ):
-    """Builds a ``degree_fn(edges, w_alive)`` hook for core.peel."""
+    """Builds the degree function ``fn(edges, w_alive) -> deg[N]`` of one
+    bucketed graph (the function an engine ``FnBackend`` wraps)."""
     tl = jnp.asarray(tiled.target_local)
     ei = jnp.asarray(tiled.edge_index)
 

@@ -345,10 +345,7 @@ def _recsys_cell(spec, shape, mesh, rules, overrides):
 
 
 def _densest_cell(spec, shape, mesh, rules, overrides):
-    from repro.core.mapreduce import (
-        make_distributed_peel,
-        make_distributed_sketched_peel,
-    )
+    from repro.core.api import Problem, default_solver
 
     p = dict(shape.params)
     n, m = p["n_nodes"], p["n_edges"]
@@ -365,51 +362,42 @@ def _densest_cell(spec, shape, mesh, rules, overrides):
         "mask": jax.ShapeDtypeStruct((m_pad,), jnp.bool_, sharding=_named(mesh, espec)),
     }
     # Every branch below is the same PeelEngine loop (core/engine.py) under
-    # shard_map; the override picks the policy / degree backend combination.
+    # shard_map, the front door's uncompacted mesh program; the override
+    # picks the policy / degree backend combination.
     policy = overrides.get("policy", "undirected")
     wants_sketch = shape.kind == "peel_sketched" or overrides.get("use_sketch")
     if policy != "undirected" and wants_sketch:
         raise ValueError(
-            f"policy={policy!r} has no distributed Count-Sketch builder yet; "
+            f"policy={policy!r} has no distributed Count-Sketch cell yet; "
             "drop the sketch config or use policy='undirected'"
         )
+    mesh_kw = dict(
+        eps=eps, max_passes=max_passes, substrate="mesh",
+        edge_axes=tuple(edge_axes),
+    )
     if policy == "topk":
-        from repro.core.mapreduce import make_distributed_topk_peel
-
-        fn = make_distributed_topk_peel(
-            mesh, edge_axes, k=int(overrides.get("k", 2)), eps=eps,
-            max_passes=max_passes, n_nodes=n,
+        problem = Problem.at_least_k(
+            k=int(overrides.get("k", 2)), min_deg_fallback=False,
+            ceil_count=True, **mesh_kw,
         )
     elif policy == "directed":
-        from repro.core.mapreduce import make_distributed_directed_peel
-
-        dfn = make_distributed_directed_peel(
-            mesh, edge_axes, eps=eps, max_passes=max_passes, n_nodes=n
-        )
-        c = float(overrides.get("c", 1.0))
-        fn = lambda src, dst, weight, mask: dfn(src, dst, weight, mask, c)
-    elif shape.kind == "peel_sketched" or overrides.get("use_sketch"):
-        fn = make_distributed_sketched_peel(
-            mesh, edge_axes, eps=eps, max_passes=max_passes, n_nodes=n,
-            t=p.get("t", overrides.get("t", 5)),
-            b=p.get("b", overrides.get("b", 1 << 17)),
-        )
-    elif overrides.get("twophase"):
-        from repro.core.mapreduce import make_distributed_peel_twophase
-
-        fn = make_distributed_peel_twophase(
-            mesh, edge_axes, eps=eps, max_passes=max_passes, n_nodes=n,
-            phase1_passes=int(overrides["twophase"]),
-            wire_dtype=overrides.get("wire_dtype", "f32"),
+        problem = Problem.directed(**mesh_kw)
+    elif wants_sketch:
+        problem = Problem.undirected(
+            backend="sketch",
+            sketch_tables=p.get("t", overrides.get("t", 5)),
+            sketch_buckets=p.get("b", overrides.get("b", 1 << 17)),
+            **mesh_kw,
         )
     else:
-        fn = make_distributed_peel(
-            mesh, edge_axes, eps=eps, max_passes=max_passes, n_nodes=n,
-            wire_dtype=overrides.get("wire_dtype", "f32"),
+        problem = Problem.undirected(
+            wire_dtype=overrides.get("wire_dtype", "f32"), **mesh_kw
         )
+    fn = default_solver.mesh_program(problem, mesh, n)
+    c_arg = (float(overrides.get("c", 1.0)),) if policy == "directed" else ()
 
     def step(src, dst, weight, mask):
-        return fn(src, dst, weight, mask)
+        return fn(src, dst, weight, mask, *c_arg)
 
     # Per-pass useful work: one weighted degree count (2 adds per endpoint
     # per edge) + threshold scan; expected passes ~ log_{1+eps} n.

@@ -1,9 +1,8 @@
 """Front-door redesign tests (core/api.py).
 
 Three guarantees:
-  1. EQUIVALENCE MATRIX — ``solve()`` is bit-identical to every legacy entry
-     point (and to a direct engine lowering) across the policy × backend ×
-     substrate cells.
+  1. EQUIVALENCE MATRIX — ``solve()`` is bit-identical to a direct engine
+     lowering across the policy × backend × substrate cells.
   2. BATCHING — ``solve_batch`` (multi-eps / multi-c / stacked graphs) is
      bit-identical to a Python loop of per-item ``solve`` calls and runs as
      ONE traced program.
@@ -16,7 +15,6 @@ the vmapped path agree to the bit.
 """
 
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
@@ -30,16 +28,11 @@ from repro.core import (
     Problem,
     Solver,
     StreamingDensest,
-    chunked_from_arrays,
-    densest_directed_search,
-    densest_subgraph,
-    densest_subgraph_at_least_k,
-    densest_subgraph_directed,
-    densest_subgraph_distributed,
-    densest_subgraph_sketched,
+    c_grid,
     solve,
     solve_batch,
 )
+from repro.core.countsketch import SketchBackend, make_sketch_params
 from repro.core.engine import (
     AtLeastKFraction,
     DirectedST,
@@ -47,8 +40,10 @@ from repro.core.engine import (
     UndirectedThreshold,
     run_peel,
 )
+from repro.core.streaming import chunked_from_arrays
 from repro.graph.edgelist import EdgeList
 from repro.graph.generators import (
+    chung_lu_power_law,
     directed_planted,
     erdos_renyi,
     planted_dense_subgraph,
@@ -78,7 +73,7 @@ def _same_result(r, l):
 
 
 # ---------------------------------------------------------------------------
-# 1. Equivalence matrix: solve() vs legacy entry points and direct lowering
+# 1. Equivalence matrix: solve() vs a direct lowering onto the engine
 # ---------------------------------------------------------------------------
 
 
@@ -86,9 +81,6 @@ def _same_result(r, l):
 def test_solve_undirected_matches_legacy_and_engine(eps):
     edges = _und()
     r = solve(edges, Problem.undirected(eps=eps, track_history=True))
-    legacy = densest_subgraph(edges, eps=eps)
-    _same_result(r, legacy)
-    _same(r.history_n, legacy.history_n)
     # Independent lowering straight onto the engine.
     mp = Problem.undirected(eps=eps).resolved_max_passes(edges.n_nodes)
     ref = jax.jit(
@@ -97,6 +89,7 @@ def test_solve_undirected_matches_legacy_and_engine(eps):
         )
     )(edges)
     _same_result(r, ref)
+    _same(r.history_n, ref.history_n)
     assert r.provenance.backend == "exact"
     assert r.provenance.substrate == "jit"
 
@@ -122,8 +115,6 @@ def test_solve_at_least_k_matches_legacy_and_engine(variant):
         )
     )(edges)
     _same_result(r, ref)
-    if fallback:  # the single-device legacy realization
-        _same_result(r, densest_subgraph_at_least_k(edges, k=k, eps=eps))
 
 
 @pytest.mark.parametrize("c", [0.5, 1.0, 2.0])
@@ -132,7 +123,6 @@ def test_solve_directed_matches_legacy_and_engine(c):
     eps = 0.5
     prob = Problem.directed(c=c, eps=eps)
     r = solve(edges, prob)
-    _same_result(r, densest_subgraph_directed(edges, c=c, eps=eps))
     mp = prob.resolved_max_passes(edges.n_nodes)
     ref = jax.jit(
         lambda e: run_peel(
@@ -143,13 +133,22 @@ def test_solve_directed_matches_legacy_and_engine(c):
 
 
 def test_solve_directed_grid_matches_legacy_search():
+    """The c-grid search is the best of one engine lowering per grid c."""
     edges = _dir()
-    r = solve(edges, Problem.directed(c=None, eps=0.5))
-    legacy, best_c, rhos, passes = densest_directed_search(edges, eps=0.5)
-    assert r.extras["best_c"] == best_c
+    prob = Problem.directed(c=None, eps=0.5)
+    r = solve(edges, prob)
+    mp = prob.resolved_max_passes(edges.n_nodes)
+    peel = jax.jit(
+        lambda e, c: run_peel(e, DirectedST(eps=0.5, c=c), ExactBackend(), mp)
+    )
+    grid = c_grid(edges.n_nodes, prob.c_delta)
+    refs = [peel(edges, jnp.float32(c)) for c in grid]
+    rhos = [float(o.best_density) for o in refs]
+    best = int(np.argmax(rhos))  # the first of equal densities, like solve
+    assert r.extras["best_c"] == float(grid[best])
     np.testing.assert_array_equal(r.extras["c_density"], rhos)
-    np.testing.assert_array_equal(r.extras["c_passes"], passes)
-    _same_result(r, legacy)
+    np.testing.assert_array_equal(r.extras["c_passes"], [int(o.passes) for o in refs])
+    _same_result(r, refs[best])
 
 
 def test_solve_sketch_matches_legacy_sketched():
@@ -160,7 +159,15 @@ def test_solve_sketch_matches_legacy_sketched():
         sketch_seed=seed, track_history=True,
     )
     r = solve(edges, prob)
-    _same_result(r, densest_subgraph_sketched(edges, eps=0.5, t=t, b=b, seed=seed))
+    mp = prob.resolved_max_passes(edges.n_nodes)
+    ref = jax.jit(
+        lambda e: run_peel(
+            e, UndirectedThreshold(0.5),
+            SketchBackend(make_sketch_params(t, b, seed)), mp, track_history=True,
+        )
+    )(edges)
+    _same_result(r, ref)
+    _same(r.history_n, ref.history_n)
     assert r.provenance.backend == "sketch"
 
 
@@ -180,8 +187,30 @@ def test_solve_mesh_matches_jit():
     rm = solve(edges, Problem.undirected(eps=0.5, substrate="mesh"), mesh=mesh)
     rj = solve(edges, Problem.undirected(eps=0.5))
     _same_result(rm, rj)
-    _same_result(rm, densest_subgraph_distributed(edges, mesh, ("data",), eps=0.5))
     assert rm.provenance.substrate == "mesh"
+
+
+MESH_CELLS = {
+    "at_least_k": (_und, lambda **kw: Problem.at_least_k(k=30, eps=0.5, **kw)),
+    "directed": (_dir, lambda **kw: Problem.directed(c=1.0, eps=0.5, **kw)),
+    "sketch": (_und, lambda **kw: Problem.undirected(
+        eps=0.5, backend="sketch", sketch_tables=5, sketch_buckets=1 << 6,
+        sketch_seed=7, **kw)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(MESH_CELLS))
+def test_solve_mesh_cells_match_jit(cell):
+    """The mesh cells of Algorithm 2, Algorithm 3 at c=1 and the §5.1
+    sketch (64 buckets: colliding counters) answer as the jit substrate's
+    uncompacted loop, bit for bit, on a one-device mesh."""
+    graph, problem = MESH_CELLS[cell]
+    edges = graph()
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("data",))
+    rm = solve(edges, problem(substrate="mesh"), mesh=mesh)
+    rj = solve(edges, problem(compaction="off"))
+    assert rm.provenance.substrate == "mesh"
+    _same_full(rm, rj)
 
 
 def test_solve_streaming_matches_jit_and_driver():
@@ -195,7 +224,7 @@ def test_solve_streaming_matches_jit_and_driver():
     _same(rs.best_alive, rj.best_alive)
     assert float(rs.best_density) == pytest.approx(float(rj.best_density), rel=1e-5)
     assert int(rs.passes) == int(rj.passes)
-    # And it is the same driver the legacy entry point runs.
+    # And it is the StreamingDensest driver run directly.
     mask = np.asarray(edges.mask)
     st = StreamingDensest(
         chunked_from_arrays(
@@ -398,10 +427,6 @@ def test_cache_ignores_fields_the_program_never_reads():
 
 def test_solve_rejects_silently_dropped_kwargs():
     edges = _und()
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("data",))
-    with pytest.raises(ValueError):
-        solve(edges, Problem.undirected(substrate="mesh"), mesh=mesh,
-              degree_fn=lambda e, w: w)
     with pytest.raises(ValueError):
         solve(edges, Problem.undirected(), checkpoint_dir="/tmp/nope")
 
@@ -454,40 +479,72 @@ def _same_full(a, b):
         _same(a.t_alive, b.t_alive)
 
 
-@pytest.mark.parametrize("mode", ["geometric", "twophase"])
+def _chung_lu():
+    """The benchmark cells' degree law at exponent 2.2: a hub-dominated
+    core (perfbench/graphgen.py)."""
+    return chung_lu_power_law(n=2000, exponent=2.2, avg_deg=8.0, seed=6)
+
+
+def _int_weighted(g, seed=0):
+    """``g`` with integer edge weights from 2 to 5."""
+    w = np.random.default_rng(seed).integers(2, 6, g.n_edges_padded)
+    return dataclasses.replace(g, weight=jnp.asarray(w, jnp.float32))
+
+
+def _floor():
+    """A graph whose host ladder ends at its bucket floor: the last rung
+    triggers and cannot compact, so it finishes uncompacted."""
+    return planted_dense_subgraph(600, avg_deg=3, k=14, p_dense=0.9, seed=2)[0]
+
+
+UNDIRECTED_GRAPHS = {"planted": _und, "chung_lu": _chung_lu, "floor": _floor}
+WEIGHTED_GRAPHS = {
+    "planted": (_und, _dir),
+    "int_weights": (lambda: _int_weighted(_und()), lambda: _int_weighted(_dir())),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(UNDIRECTED_GRAPHS))
 @pytest.mark.parametrize("eps", [0.1, 0.5])
-def test_compaction_undirected_jit_bit_identical(mode, eps):
-    edges = _und()
+def test_compaction_undirected_jit_bit_identical(graph, eps):
+    edges = UNDIRECTED_GRAPHS[graph]()
     s = Solver()
-    off = s.solve(edges, Problem.undirected(eps=eps, track_history=True))
+    off = s.solve(
+        edges, Problem.undirected(eps=eps, track_history=True, compaction="off")
+    )
     on = s.solve(
-        edges, Problem.undirected(eps=eps, track_history=True, compaction=mode)
+        edges,
+        Problem.undirected(eps=eps, track_history=True, compaction="geometric"),
     )
     _same_full(off, on)
     _same(off.history_n, on.history_n)
     _same(off.history_rho, on.history_rho)
-    assert on.provenance.compaction == mode
+    assert on.provenance.compaction == "geometric"
     lad = on.extras["compaction"]
     assert lad["passes"] == int(off.passes)
     assert sum(seg["passes"] for seg in lad["segments"]) == int(off.passes)
+    if graph == "floor":
+        last, before = lad["segments"][-1], lad["segments"][-2]
+        assert last["compact_below"] is None and last["passes"] > 0
+        assert (last["n_buf"], last["m_buf"]) == (before["n_buf"], before["m_buf"])
 
 
-@pytest.mark.parametrize("mode", ["geometric", "twophase"])
-def test_compaction_at_least_k_jit_bit_identical(mode):
-    edges = _und()
+@pytest.mark.parametrize("graph", sorted(WEIGHTED_GRAPHS))
+def test_compaction_at_least_k_jit_bit_identical(graph):
+    edges = WEIGHTED_GRAPHS[graph][0]()
     s = Solver()
-    off = s.solve(edges, Problem.at_least_k(k=30, eps=0.5))
-    on = s.solve(edges, Problem.at_least_k(k=30, eps=0.5, compaction=mode))
+    off = s.solve(edges, Problem.at_least_k(k=30, eps=0.5, compaction="off"))
+    on = s.solve(edges, Problem.at_least_k(k=30, eps=0.5, compaction="geometric"))
     _same_full(off, on)
 
 
-@pytest.mark.parametrize("mode", ["geometric", "twophase"])
+@pytest.mark.parametrize("graph", sorted(WEIGHTED_GRAPHS))
 @pytest.mark.parametrize("c", [0.5, 1.0, 2.0])
-def test_compaction_directed_jit_bit_identical(mode, c):
-    edges = _dir()
+def test_compaction_directed_jit_bit_identical(graph, c):
+    edges = WEIGHTED_GRAPHS[graph][1]()
     s = Solver()
-    off = s.solve(edges, Problem.directed(c=c, eps=0.5))
-    on = s.solve(edges, Problem.directed(c=c, eps=0.5, compaction=mode))
+    off = s.solve(edges, Problem.directed(c=c, eps=0.5, compaction="off"))
+    on = s.solve(edges, Problem.directed(c=c, eps=0.5, compaction="geometric"))
     _same_full(off, on)
 
 
@@ -562,9 +619,9 @@ def test_compaction_ladder_shares_programs_across_c():
     edges = _dir()
     p1 = Problem.directed(c=0.5, eps=0.5, compaction="geometric").resolve(edges.n_nodes)
     p2 = Problem.directed(c=1.0, eps=0.5, compaction="geometric").resolve(edges.n_nodes)
-    for kind in ("cseg", "cseg_mesh", "ladder_mesh"):
-        k1 = s._key(kind, p1, 32, 128, 1024, "float32", None, (64,))
-        k2 = s._key(kind, p2, 32, 128, 1024, "float32", None, (64,))
+    for kind in ("cseg", "ladder_mesh"):
+        k1 = s._key(kind, p1, 32, 128, 1024, "float32", (64,))
+        k2 = s._key(kind, p2, 32, 128, 1024, "float32", (64,))
         assert k1 == k2
     a = s.solve(edges, Problem.directed(c=0.5, eps=0.5, compaction="geometric"))
     b = s.solve(edges, Problem.directed(c=1.0, eps=0.5, compaction="geometric"))
@@ -584,19 +641,25 @@ def test_compaction_ladder_programs_are_cached():
     assert r2.provenance.cache_hit
 
 
-@pytest.mark.parametrize("mode", ["geometric", "twophase"])
-def test_compaction_zero_pass_runs_match_off(mode):
+ZERO_PASS_GRAPHS = {
+    "erdos_renyi": lambda: erdos_renyi(50, avg_deg=4, seed=0),
+    "chung_lu": _chung_lu,
+}
+
+
+@pytest.mark.parametrize("graph", sorted(ZERO_PASS_GRAPHS))
+def test_compaction_zero_pass_runs_match_off(graph):
     """Degenerate runs where the loop never executes a pass (k > n, or
     max_passes=0) must still match 'off', which returns the full initial
     set (regression: the ladder used to return an all-empty best set)."""
-    edges = erdos_renyi(50, avg_deg=4, seed=0)
+    edges = ZERO_PASS_GRAPHS[graph]()
     s = Solver()
     for prob in (
-        Problem.at_least_k(k=60, eps=0.5),
-        Problem.undirected(eps=0.5, max_passes=0),
+        Problem.at_least_k(k=edges.n_nodes + 10, eps=0.5, compaction="off"),
+        Problem.undirected(eps=0.5, max_passes=0, compaction="off"),
     ):
         off = s.solve(edges, prob)
-        on = s.solve(edges, dataclasses.replace(prob, compaction=mode))
+        on = s.solve(edges, dataclasses.replace(prob, compaction="geometric"))
         _same_full(off, on)
 
 
@@ -613,16 +676,13 @@ def test_compaction_auto_resolution_and_validation():
         Problem.undirected(backend="sketch", compaction="auto").resolve(100).compaction
         == "geometric"
     )
-    for mode in ("geometric", "twophase"):
-        sk = Problem.undirected(backend="sketch", compaction=mode).resolve(100)
-        assert (sk.backend, sk.substrate, sk.compaction) == ("sketch", "jit", mode)
-    # The mesh ladders renumber node ids: a sketch there stays off.
+    sk = Problem.undirected(backend="sketch", compaction="geometric").resolve(100)
+    assert (sk.backend, sk.substrate, sk.compaction) == ("sketch", "jit", "geometric")
+    # The mesh ladder counts exact degrees only: a sketch there stays off.
     mesh_sk = Problem.undirected(backend="sketch", substrate="mesh")
     assert mesh_sk.resolve(100).compaction == "off"
     with pytest.raises(ValueError):
         dataclasses.replace(mesh_sk, compaction="geometric").resolve(100)
-    with pytest.raises(ValueError):
-        Problem.undirected(substrate="streaming", compaction="twophase").resolve(100)
     with pytest.raises(ValueError):
         Problem(compaction="nope")
     # Explicit ladder modes are rejected by the batched driver; auto is not.
@@ -636,27 +696,32 @@ def test_compaction_auto_resolution_and_validation():
         edges, Problem.undirected(max_passes=16, compaction="auto"), eps=[0.5]
     )
     assert rb.provenance.compaction == "off"
-    # degree_fn hooks bind one buffer; an EXPLICIT ladder conflicts...
-    with pytest.raises(ValueError):
-        solve(
-            edges, Problem.undirected(compaction="geometric"),
-            degree_fn=lambda e, w: w,
+
+
+def test_twophase_schedule_is_rejected():
+    """The one compaction schedule is the geometric ladder: 'twophase' is
+    an unknown mode, and Problem has no phase-1 pass budget."""
+    with pytest.raises(ValueError, match="compaction='twophase' not in"):
+        Problem(compaction="twophase")
+    with pytest.raises(TypeError):
+        Problem(twophase_passes=8)
+
+
+@pytest.mark.parametrize("method", ["solve", "solve_batch"])
+def test_degree_fn_hook_is_rejected(method):
+    """Degrees come from ``Problem.backend``; there is no custom-degree
+    hook on the front door."""
+    edges = _und()
+    kw = {"eps": [0.5]} if method == "solve_batch" else {}
+    with pytest.raises(TypeError, match="degree_fn"):
+        getattr(Solver(), method)(
+            edges, Problem.undirected(compaction="off"),
+            degree_fn=lambda e, w: w, **kw,
         )
-    # ...but the 'auto' DEFAULT quietly falls back to the uncompacted loop
-    # (regression: the flip used to break every existing degree_fn call).
-    from repro.core.engine import segment_degree_count
-
-    def hook(e, w_alive):
-        return segment_degree_count(e.src, e.dst, w_alive, e.n_nodes)[0]
-
-    s = Solver()
-    r_hook = s.solve(edges, Problem.undirected(eps=0.5), degree_fn=hook)
-    assert r_hook.provenance.compaction == "off"
-    _same_full(r_hook, s.solve(edges, Problem.undirected(eps=0.5, compaction="off")))
 
 
 # ---------------------------------------------------------------------------
-# Result type and deprecation aliases
+# Result type
 # ---------------------------------------------------------------------------
 
 
@@ -670,28 +735,6 @@ def test_result_is_pytree_with_static_provenance():
     assert mapped.provenance == r.provenance  # static metadata survives
     assert r.nodes().size == int(r.best_size)
     assert isinstance(r, DenseSubgraphResult)
-
-
-@pytest.mark.parametrize(
-    "module,name",
-    [
-        ("repro.core", "PeelResult"),
-        ("repro.core", "PeelTopKResult"),
-        ("repro.core", "DirectedPeelResult"),
-        ("repro.core.peel", "PeelResult"),
-        ("repro.core.peel_topk", "PeelTopKResult"),
-        ("repro.core.peel_directed", "DirectedPeelResult"),
-    ],
-)
-def test_deprecated_result_aliases_warn(module, name):
-    import importlib
-
-    mod = importlib.import_module(module)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        alias = getattr(mod, name)
-    assert alias is DenseSubgraphResult
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
 
 
 def test_problem_validation():

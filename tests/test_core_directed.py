@@ -4,14 +4,24 @@ pass bound, planted S->T recovery."""
 import numpy as np
 import pytest
 
-from repro.core import (
-    c_grid,
-    densest_directed_brute,
-    densest_directed_search,
-    densest_subgraph_directed,
-)
+from repro.core import Problem, c_grid, densest_directed_brute, solve, solve_batch
 from repro.graph import from_numpy
 from repro.graph.generators import directed_planted, erdos_renyi
+
+
+def _directed(edges, c, eps):
+    """Algorithm 3 at one c, on the uncompacted loop."""
+    return solve(edges, Problem.directed(c=c, eps=eps, compaction="off"))
+
+
+def _search(edges, eps, delta=2.0):
+    """Algorithm 3 over the geometric c grid, on the uncompacted loop:
+    (result, best c, per-c densities, per-c passes)."""
+    res = solve(
+        edges, Problem.directed(c=None, eps=eps, c_delta=delta, compaction="off")
+    )
+    ex = res.extras
+    return res, ex["best_c"], np.asarray(ex["c_density"]), np.asarray(ex["c_passes"])
 
 
 def test_directed_brute_comparison_tiny():
@@ -23,7 +33,7 @@ def test_directed_brute_comparison_tiny():
         keep = src != dst
         edges = from_numpy(src[keep], dst[keep], n, directed=True)
         _, _, rho_star = densest_directed_brute(edges)
-        res, best_c, _, _ = densest_directed_search(edges, eps=0.05, delta=1.3)
+        res, best_c, _, _ = _search(edges, eps=0.05, delta=1.3)
         # (2+2eps) * delta guarantee.
         bound = rho_star / (2 * 1.05 * 1.3)
         assert float(res.best_density) >= bound - 1e-6
@@ -34,7 +44,7 @@ def test_planted_directed_block():
     edges, s_ids, t_ids = directed_planted(
         300, avg_deg=3, ks=20, kt=15, p_dense=0.9, seed=1
     )
-    res, best_c, rhos, passes = densest_directed_search(edges, eps=0.5, delta=2.0)
+    res, best_c, rhos, passes = _search(edges, eps=0.5, delta=2.0)
     s_found = set(np.nonzero(np.asarray(res.best_s))[0].tolist())
     t_found = set(np.nonzero(np.asarray(res.best_t))[0].tolist())
     assert len(s_found & set(s_ids.tolist())) >= 0.7 * len(s_ids)
@@ -45,7 +55,7 @@ def test_planted_directed_block():
 
 def test_directed_pass_bound():
     edges = erdos_renyi(500, avg_deg=6, seed=2, directed=True)
-    r = densest_subgraph_directed(edges, c=1.0, eps=0.5)
+    r = _directed(edges, c=1.0, eps=0.5)
     # Lemma 13: O(log_{1+eps} n) for each of S and T.
     import math
 
@@ -64,7 +74,7 @@ def test_c_grid_covers_range():
 
 def test_best_pair_density_matches_recomputation():
     edges, _, _ = directed_planted(200, avg_deg=3, ks=12, kt=12, p_dense=0.8, seed=5)
-    res = densest_subgraph_directed(edges, c=1.0, eps=0.5)
+    res = _directed(edges, c=1.0, eps=0.5)
     s = np.asarray(res.best_s)
     t = np.asarray(res.best_t)
     mask = np.asarray(edges.mask)
@@ -78,17 +88,14 @@ def test_best_pair_density_matches_recomputation():
 def test_vmapped_c_search_matches_loop():
     """One-program vmapped c-grid == the python-loop search (same densities
     for every c, same winner)."""
-    from repro.core.peel_directed import (
-        densest_directed_search,
-        densest_directed_search_vmapped,
-    )
-    from repro.graph.generators import directed_planted
-
     edges, _, _ = directed_planted(
         n=2000, avg_deg=5.0, ks=40, kt=16, p_dense=0.5, seed=4
     )
-    best, best_c, rhos, passes = densest_directed_search(edges, eps=0.5)
-    vc, vrho, vrhos, vpasses = densest_directed_search_vmapped(edges, eps=0.5)
+    best, best_c, rhos, passes = _search(edges, eps=0.5)
+    cs = c_grid(edges.n_nodes, 2.0)
+    sweep = solve_batch(edges, Problem.directed(eps=0.5), c=cs)
+    vrhos = np.asarray(sweep.best_density)
     np.testing.assert_allclose(vrhos, rhos, rtol=1e-5)
-    assert vc == best_c
-    assert vrho == pytest.approx(float(best.best_density), rel=1e-6)
+    best_i = int(np.argmax(vrhos))
+    assert float(cs[best_i]) == best_c
+    assert float(vrhos[best_i]) == pytest.approx(float(best.best_density), rel=1e-6)

@@ -12,18 +12,13 @@ import numpy as np
 import pytest
 
 from perfbench import graphgen, sketch_reference
-from repro.core import (
-    Problem,
-    Solver,
-    api,
-    countsketch,
-    densest_subgraph,
-    densest_subgraph_sketched,
+from repro.core import Problem, Solver, api, countsketch, solve
+from repro.core.countsketch import (
+    SketchCounters,
     make_sketch_params,
     query_degrees,
     sketch_degrees_from_edges,
 )
-from repro.core.countsketch import SketchCounters
 from repro.core.density import alive_edge_weight
 from repro.graph.edgelist import EdgeList
 from repro.graph.generators import chung_lu_power_law, planted_dense_subgraph
@@ -68,10 +63,11 @@ def test_sketch_error_decreases_with_buckets():
 def test_sketched_peeling_close_to_exact():
     """Table 4 analogue: sketched density within a modest factor of exact."""
     edges, _ = planted_dense_subgraph(1500, avg_deg=4, k=40, p_dense=0.8, seed=4)
-    exact = float(densest_subgraph(edges, eps=0.5).best_density)
-    sk = float(
-        densest_subgraph_sketched(edges, eps=0.5, t=5, b=1 << 12, seed=0).best_density
-    )
+    exact = float(solve(edges, Problem.undirected(
+        eps=0.5, track_history=True, compaction="off")).best_density)
+    sk = float(solve(edges, Problem.undirected(
+        eps=0.5, track_history=True, backend="sketch", sketch_tables=5,
+        sketch_buckets=1 << 12, sketch_seed=0)).best_density)
     assert sk >= 0.75 * exact  # paper sees 0.89-1.05 at eps<=1
     assert sk <= 1.25 * exact
 
@@ -200,26 +196,45 @@ def test_sketched_ladder_answers_as_the_uncompacted_loop(objective, seed):
         assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), field
 
 
-@pytest.mark.parametrize("compaction", ["geometric", "twophase"])
-def test_sketched_ladder_keeps_node_ids(compaction):
-    g, _, _ = _chung_lu(7)
-    res = Solver().solve(g, _sketch_problem(256, compaction=compaction))
+KEEP_ID_GRAPHS = {
+    # 256 buckets on the benchmark's degree law.
+    "chung_lu": lambda: (_chung_lu(7)[0], 256),
+    # Integer weights, colliding counters (about 12 nodes a bucket), down
+    # to the edge-bucket floor.
+    "weighted_floor": lambda: (_weighted(11, False), 256),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(KEEP_ID_GRAPHS))
+def test_sketched_ladder_keeps_node_ids(graph):
+    g, b = KEEP_ID_GRAPHS[graph]()
+    solver = Solver()
+    res = solver.solve(g, _sketch_problem(b, compaction="geometric"))
     segs = res.extras["compaction"]["segments"]
     assert len(segs) > 1
     assert all(s["n_buf"] == N_NODES for s in segs)
     assert all(b["m_buf"] < a["m_buf"] for a, b in zip(segs, segs[1:]))
     assert res.extras["compaction"]["edge_slots_scanned"] == \
         sum(s["passes"] * s["m_buf"] for s in segs)
+    if graph == "weighted_floor":
+        # The smallest edge bucket gets no trigger: the ladder ends there.
+        assert segs[-1]["m_buf"] == api._COMPACT_MIN_EDGES
+        assert segs[-1]["compact_below"] is None
+    off = solver.solve(g, _sketch_problem(b, compaction="off"))
+    for field in LADDER_FIELDS:
+        x, y = np.asarray(getattr(res, field)), np.asarray(getattr(off, field))
+        assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), field
 
 
-@pytest.mark.parametrize("compaction", ["geometric", "twophase"])
-def test_sketch_on_the_mesh_keeps_its_ladder_off(compaction):
-    """The mesh ladders renumber node ids, so a sketch there stays off."""
-    prob = Problem.undirected(backend="sketch", substrate="mesh")
+@pytest.mark.parametrize("objective", ["undirected", "directed"])
+def test_sketch_on_the_mesh_keeps_its_ladder_off(objective):
+    """The mesh ladder counts exact degrees only, so a sketch there stays
+    off, and an explicit ladder is refused."""
+    prob = OBJECTIVES[objective](backend="sketch", substrate="mesh")
     assert prob.resolve(100).compaction == "off"
     with pytest.raises(ValueError, match="only the jit ladder keeps them"):
-        Problem.undirected(
-            backend="sketch", substrate="mesh", compaction=compaction
+        OBJECTIVES[objective](
+            backend="sketch", substrate="mesh", compaction="geometric"
         ).resolve(100)
 
 

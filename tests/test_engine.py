@@ -16,8 +16,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.core import densest_subgraph_exact, density_of
-from repro.core.countsketch import SketchBackend, make_sketch_params, sketched_degree_fn
+from repro.core import densest_subgraph_exact
+from repro.core.countsketch import (
+    SketchBackend,
+    make_sketch_params,
+    query_degrees,
+    sketch_degrees_from_edges,
+)
+from repro.core.density import density_of
 from repro.core.engine import (
     AtLeastKFraction,
     DirectedST,
@@ -254,21 +260,27 @@ def test_matrix_directed_st(backend, c):
 
 
 # ---------------------------------------------------------------------------
-# Approximate backends: sketch (class == legacy degree_fn hook) and Pallas
+# Approximate backends: sketch (class == FnBackend over the sketch) and Pallas
 # ---------------------------------------------------------------------------
 
 
 def test_sketch_backend_matches_degree_fn_hook():
-    """SketchBackend through the engine == the pre-refactor degree_fn path."""
+    """SketchBackend through the engine == an FnBackend that sketches the
+    alive edges and queries every node."""
     edges, _ = planted_dense_subgraph(600, avg_deg=4, k=30, p_dense=0.8, seed=1)
     params = make_sketch_params(t=5, b=1 << 12, seed=7)
     mp = 64
+
+    def sketched_degrees(e, w_alive):
+        counters = sketch_degrees_from_edges(params, e, w_alive)
+        return query_degrees(params, counters, jnp.arange(e.n_nodes, dtype=jnp.int32))
+
     a = jax.jit(
         lambda e: run_peel(e, UndirectedThreshold(0.5), SketchBackend(params), mp)
     )(edges)
     b = jax.jit(
         lambda e: run_peel(
-            e, UndirectedThreshold(0.5), FnBackend(sketched_degree_fn(params)), mp
+            e, UndirectedThreshold(0.5), FnBackend(sketched_degrees), mp
         )
     )(edges)
     np.testing.assert_array_equal(np.asarray(a.best_alive), np.asarray(b.best_alive))

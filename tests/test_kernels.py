@@ -80,14 +80,12 @@ def test_peel_degree_weighted_dtypes():
 
 def test_peel_with_pallas_degree_fn_matches_exact():
     """Algorithm 1 driven by the Pallas degree kernel == exact-degree run."""
-    from repro.core.peel import densest_subgraph
-    from repro.kernels.peel_degree.ops import degree_fn_from_tiling, tiling_for_edges
+    from repro.core import Problem, solve
 
     edges, _ = planted_dense_subgraph(n=300, avg_deg=4.0, k=25, p_dense=0.8, seed=3)
-    tiled = tiling_for_edges(edges, tile_size=64, block=128)
-    fn = degree_fn_from_tiling(tiled, use_pallas=True)
-    res_pallas = densest_subgraph(edges, eps=0.5, degree_fn=fn, track_history=False)
-    res_exact = densest_subgraph(edges, eps=0.5, track_history=False)
+    res_pallas = solve(edges, Problem.undirected(
+        eps=0.5, backend="pallas", tile_size=64, tile_block=128, compaction="off"))
+    res_exact = solve(edges, Problem.undirected(eps=0.5, compaction="off"))
     assert float(res_pallas.best_density) == pytest.approx(
         float(res_exact.best_density), rel=1e-5
     )

@@ -171,14 +171,6 @@ def test_a_graph_already_on_the_host_is_not_counted_again():
     assert again["bytes_to_device"] == first["bytes_to_device"]
 
 
-def test_ladder_bytes_are_reported_on_every_rung_schedule():
-    g = _graph()
-    res = Solver().solve(g, Problem.undirected(eps=0.5, compaction="twophase"))
-    lad = res.extras["compaction"]
-    assert lad["bytes_to_host"] > g.n_edges_padded * 13
-    assert lad["bytes_to_device"] > sum(s["m_buf"] * 13 for s in lad["segments"])
-
-
 def _lowered_peel(track_history=False):
     g = _graph()
     policy = engine.UndirectedThreshold(0.5)

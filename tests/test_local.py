@@ -205,7 +205,7 @@ def test_solve_validation_matrix():
         solve(g, PROB, seed=3)  # seed on a whole-graph substrate
     with pytest.raises(ValueError, match="mesh"):
         Solver().solve(g, PROB_LOCAL, seed=3, mesh=object())
-    with pytest.raises(ValueError, match="degree_fn"):
+    with pytest.raises(TypeError, match="degree_fn"):
         Solver().solve(g, PROB_LOCAL, seed=3, degree_fn=lambda *a: None)
 
 

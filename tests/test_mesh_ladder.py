@@ -25,12 +25,8 @@ import jax
 from jax.sharding import Mesh
 
 import repro.core.api as api_mod
-from repro.core import (
-    Problem,
-    Solver,
-    make_distributed_peel_ladder,
-    shard_edges,
-)
+from repro.core import Problem, Solver
+from repro.core.mapreduce import shard_edges
 from repro.graph.partition import ladder_schedule, pow2_bucket
 from repro.graph.generators import directed_planted, planted_dense_subgraph
 
@@ -214,20 +210,23 @@ def test_mesh_ladder_program_is_cached_and_shares_across_c(small_ladder_floor):
 
 
 def test_make_distributed_peel_ladder_builder_single_device(small_ladder_floor):
+    """The cached single program, launched by hand on arrays the caller
+    shards (``Solver.mesh_ladder_program``)."""
     edges = _und()
     mesh = _mesh1()
-    run = make_distributed_peel_ladder(
-        mesh, ("data",), eps=0.5, n_nodes=edges.n_nodes,
-        m_edges=edges.n_edges_padded,
+    fn, schedule, n_shards, _ = Solver().mesh_ladder_program(
+        Problem.undirected(eps=0.5, substrate="mesh", compaction="geometric"),
+        mesh, edges.n_nodes, edges.n_edges_padded,
     )
-    assert run.n_edge_slots == run.schedule[0] * 1
+    assert n_shards == 1
     # Rung 0 is the exact input buffer; the tail is pow2-bucketed and
     # strictly descending.
-    assert all(a > b for a, b in zip(run.schedule, run.schedule[1:]))
-    assert all(pow2_bucket(c) == c for c in run.schedule[1:])
-    padded = edges.with_padding(run.n_edge_slots)
+    assert all(a > b for a, b in zip(schedule, schedule[1:]))
+    assert all(pow2_bucket(c) == c for c in schedule[1:])
+    padded = edges.with_padding(schedule[0] * n_shards)
     sh = shard_edges(padded, mesh, ("data",))
-    out = run(sh.src, sh.dst, sh.weight, sh.mask)
+    out, rung_t = fn(sh.src, sh.dst, sh.weight, sh.mask)
+    assert rung_t.shape == (len(schedule),) and int(rung_t[-1]) == int(out.passes)
     ref = Solver().solve(edges, Problem.undirected(eps=0.5, compaction="off"))
     np.testing.assert_array_equal(
         np.asarray(out.best_alive), np.asarray(ref.best_alive)
@@ -248,7 +247,8 @@ _LADDER_8DEV_SCRIPT = textwrap.dedent(
     import jax
     import jax.numpy as jnp
     import repro.core.api as api_mod
-    from repro.core import Problem, Solver, make_distributed_peel_ladder, shard_edges
+    from repro.core import Problem, Solver
+    from repro.core.mapreduce import shard_edges
     from repro.graph.edgelist import EdgeList
     from repro.graph.generators import planted_dense_subgraph
 
@@ -316,14 +316,14 @@ _LADDER_8DEV_SCRIPT = textwrap.dedent(
     )
     same(off2, onp_)
 
-    # Raw single-program builder parity.
-    run = make_distributed_peel_ladder(
-        mesh, ("data",), eps=0.2, n_nodes=edges.n_nodes,
-        m_edges=edges.n_edges_padded,
+    # The cached single program launched by hand: parity.
+    fn, schedule, n_shards, _ = s.mesh_ladder_program(
+        Problem.undirected(eps=0.2, substrate="mesh", compaction="geometric"),
+        mesh, edges.n_nodes, edges.n_edges_padded,
     )
-    padded = edges.with_padding(run.n_edge_slots)
+    padded = edges.with_padding(schedule[0] * n_shards)
     sh = shard_edges(padded, mesh, ("data",))
-    out = run(sh.src, sh.dst, sh.weight, sh.mask)
+    out, _ = fn(sh.src, sh.dst, sh.weight, sh.mask)
     assert np.array_equal(np.asarray(out.best_alive), np.asarray(off.best_alive))
     assert int(out.passes) == int(off.passes)
     print("MESH_LADDER_8DEV_OK")

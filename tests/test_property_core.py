@@ -8,14 +8,16 @@ hypothesis = pytest.importorskip(
 )
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (
-    densest_subgraph,
-    densest_subgraph_at_least_k,
-    densest_subgraph_brute,
-    density_of,
-    max_passes_bound,
-)
+from repro.core import Problem, densest_subgraph_brute, solve
+from repro.core.density import density_of, max_passes_bound
 from repro.graph import from_numpy
+
+
+def _peel(edges, eps):
+    """Algorithm 1 through the front door, on the uncompacted loop."""
+    return solve(
+        edges, Problem.undirected(eps=eps, track_history=True, compaction="off")
+    )
 
 
 @st.composite
@@ -40,7 +42,7 @@ def small_graphs(draw):
 @settings(max_examples=25, deadline=None)
 def test_property_approximation_and_passes(edges, eps):
     _, rho_star = densest_subgraph_brute(edges)
-    res = densest_subgraph(edges, eps=eps)
+    res = _peel(edges, eps=eps)
     # (2+2eps) guarantee and validity.
     assert float(res.best_density) >= rho_star / (2 * (1 + eps)) - 1e-5
     assert float(res.best_density) <= rho_star + 1e-5
@@ -55,7 +57,10 @@ def test_property_approximation_and_passes(edges, eps):
 @given(small_graphs(), st.integers(2, 6))
 @settings(max_examples=15, deadline=None)
 def test_property_topk_size(edges, k):
-    res = densest_subgraph_at_least_k(edges, k=min(k, edges.n_nodes), eps=0.5)
+    res = solve(
+        edges,
+        Problem.at_least_k(k=min(k, edges.n_nodes), eps=0.5, compaction="off"),
+    )
     assert int(res.best_size) >= min(k, edges.n_nodes)
 
 
@@ -78,8 +83,8 @@ def test_property_monotone_under_weight_scaling(n, scale, seed):
     w = rng.uniform(0.5, 2.0, keep.sum()).astype(np.float32)
     e1 = from_numpy(src[keep], dst[keep], n, weight=w)
     e2 = from_numpy(src[keep], dst[keep], n, weight=w * scale)
-    r1 = densest_subgraph(e1, eps=0.5)
-    r2 = densest_subgraph(e2, eps=0.5)
+    r1 = _peel(e1, eps=0.5)
+    r2 = _peel(e2, eps=0.5)
     assert float(r2.best_density) == pytest.approx(
         scale * float(r1.best_density), rel=1e-4
     )

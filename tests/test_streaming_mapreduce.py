@@ -13,8 +13,16 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import StreamingDensest, chunked_from_arrays, densest_subgraph
+from repro.core import Problem, StreamingDensest, solve
+from repro.core.streaming import chunked_from_arrays
 from repro.graph.generators import erdos_renyi, planted_dense_subgraph
+
+
+def _peel(edges, eps):
+    """Algorithm 1 through the front door, on the uncompacted jit loop."""
+    return solve(
+        edges, Problem.undirected(eps=eps, track_history=True, compaction="off")
+    )
 
 
 def _edges_np(edges):
@@ -28,7 +36,7 @@ def _edges_np(edges):
 
 def test_streaming_matches_in_memory():
     edges, _ = planted_dense_subgraph(800, avg_deg=4, k=30, p_dense=0.8, seed=0)
-    ref = densest_subgraph(edges, eps=0.5)
+    ref = _peel(edges, eps=0.5)
     src, dst, w = _edges_np(edges)
     drv = StreamingDensest(
         chunked_from_arrays(src, dst, w, chunk=257),
@@ -46,7 +54,7 @@ def test_streaming_checkpoint_restart(tmp_path):
     """Kill the run after a few passes; resuming must give identical output."""
     edges = erdos_renyi(600, avg_deg=8, seed=1)
     src, dst, w = _edges_np(edges)
-    ref = densest_subgraph(edges, eps=0.5)
+    ref = _peel(edges, eps=0.5)
 
     ckpt = str(tmp_path / "ck")
     drv = StreamingDensest(
@@ -75,7 +83,7 @@ def test_streaming_checkpoint_restart(tmp_path):
 def test_streaming_speculative_reissue_is_idempotent():
     edges = erdos_renyi(400, avg_deg=6, seed=2)
     src, dst, w = _edges_np(edges)
-    ref = densest_subgraph(edges, eps=1.0)
+    ref = _peel(edges, eps=1.0)
     drv = StreamingDensest(
         chunked_from_arrays(src, dst, w, chunk=64),
         n_nodes=edges.n_nodes,
@@ -94,18 +102,19 @@ _MAPREDUCE_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax
-    from repro.core import densest_subgraph, densest_subgraph_distributed
-    from repro.core.mapreduce import make_distributed_directed_peel, shard_edges
-    from repro.core.peel_directed import densest_subgraph_directed
+    from repro.core import Problem, solve
     from repro.graph.generators import planted_dense_subgraph, directed_planted
 
     assert len(jax.devices()) == 8
     mesh = jax.make_mesh((8,), ("data",))
+    mesh_prob = Problem.undirected(
+        eps=0.5, substrate="mesh", edge_axes=("data",), compaction="off")
 
     # Undirected equivalence: identical best set + density for any sharding.
     edges, _ = planted_dense_subgraph(500, avg_deg=4, k=25, p_dense=0.8, seed=0)
-    ref = densest_subgraph(edges, eps=0.5)
-    res = densest_subgraph_distributed(edges, mesh, ("data",), eps=0.5)
+    ref = solve(edges, Problem.undirected(
+        eps=0.5, track_history=True, compaction="off"))
+    res = solve(edges, mesh_prob, mesh=mesh)
     assert abs(float(res.best_density) - float(ref.best_density)) < 1e-5
     assert (np.asarray(res.best_alive) == np.asarray(ref.best_alive)).all()
     assert int(res.passes) == int(ref.passes)
@@ -117,17 +126,17 @@ _MAPREDUCE_SCRIPT = textwrap.dedent(
     edges_p = EdgeList(
         src=edges.src[perm], dst=edges.dst[perm], weight=edges.weight[perm],
         mask=edges.mask[perm], n_nodes=edges.n_nodes)
-    res_p = densest_subgraph_distributed(edges_p, mesh, ("data",), eps=0.5)
+    res_p = solve(edges_p, mesh_prob, mesh=mesh)
     assert (np.asarray(res_p.best_alive) == np.asarray(ref.best_alive)).all()
 
     # Directed equivalence.
     dg, _, _ = directed_planted(300, avg_deg=3, ks=15, kt=15, p_dense=0.9, seed=1)
-    dref = densest_subgraph_directed(dg, c=1.0, eps=0.5)
-    dsh = shard_edges(dg, mesh, ("data",))
-    dfn = make_distributed_directed_peel(mesh, ("data",), eps=0.5, n_nodes=dsh.n_nodes)
-    ds, dt, drho, dp = dfn(dsh.src, dsh.dst, dsh.weight, dsh.mask, 1.0)
-    assert abs(float(drho) - float(dref.best_density)) < 1e-5
-    assert (np.asarray(ds) == np.asarray(dref.best_s)).all()
+    dref = solve(dg, Problem.directed(c=1.0, eps=0.5, compaction="off"))
+    dres = solve(dg, Problem.directed(
+        c=1.0, eps=0.5, substrate="mesh", edge_axes=("data",),
+        compaction="off"), mesh=mesh)
+    assert abs(float(dres.best_density) - float(dref.best_density)) < 1e-5
+    assert (np.asarray(dres.best_s) == np.asarray(dref.best_s)).all()
     print("MAPREDUCE_EQUIV_OK")
     """
 )

@@ -11,7 +11,7 @@ rung-trigger accounting regression.
 import numpy as np
 import pytest
 
-from repro.core import Problem, Solver, densest_subgraph
+from repro.core import Problem, Solver
 from repro.core.streaming import (
     StreamingDensest,
     chunked_from_arrays,
@@ -402,7 +402,9 @@ def test_spill_writer_atomic_manifest(tmp_path):
 def test_problem_stream_knobs_lowering(tmp_path):
     edges = erdos_renyi(400, avg_deg=6, seed=5)
     s = Solver()
-    ref = densest_subgraph(edges, eps=0.5)
+    ref = Solver().solve(
+        edges, Problem.undirected(eps=0.5, track_history=True, compaction="off")
+    )
     res = s.solve(
         edges,
         Problem.undirected(
@@ -450,6 +452,6 @@ def test_problem_stream_knobs_lowering(tmp_path):
     p2 = Problem.undirected(
         stream_prefetch=3, spill_dir="/x", stream_chunk=1, stream_workers=9
     ).resolve(100)
-    assert s._key("solve", p1, 8, 100, 64, "float32", None) == s._key(
-        "solve", p2, 8, 100, 64, "float32", None
+    assert s._key("solve", p1, 8, 100, 64, "float32") == s._key(
+        "solve", p2, 8, 100, 64, "float32"
     )

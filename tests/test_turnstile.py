@@ -25,10 +25,11 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core import Problem, Solver, TurnstileDensest, TurnstileSketch, solve
+from repro.core import Problem, Solver, TurnstileDensest, solve
 from repro.core import countsketch
 from repro.core.countsketch import make_sketch_params
 from repro.core.turnstile import (
+    TurnstileSketch,
     _np_edge_cells,
     _np_edge_fingerprint,
     _np_edge_level,

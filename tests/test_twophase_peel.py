@@ -1,17 +1,14 @@
-"""Two-phase compacted distributed peel == single-phase peel (same best
-density and set): compaction is pure renumbering, Lemma 4 bounds phase-2
-size.  Runs on a 1-device mesh (the collective structure is identical)."""
+"""Compacted mesh peels == the uncompacted mesh peel (same best density and
+set): compaction is pure re-bucketing, Lemma 4 bounds the shrink.  Runs on
+a 1-device mesh (the collective structure is identical)."""
 
 import jax
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from repro.core.mapreduce import (
-    make_distributed_peel,
-    make_distributed_peel_twophase,
-    shard_edges,
-)
+import repro.core.api as api_mod
+from repro.core import Problem, solve
 from repro.graph import generators as gen
 
 
@@ -19,33 +16,40 @@ def _mesh():
     return Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("data",))
 
 
-@pytest.mark.parametrize("seed,eps,k1", [(0, 0.5, 3), (1, 1.0, 2), (2, 0.3, 5)])
-def test_twophase_matches_single_phase(seed, eps, k1):
+@pytest.mark.parametrize("seed,eps", [(0, 0.5), (1, 1.0), (2, 0.3)])
+def test_twophase_matches_single_phase(seed, eps, monkeypatch):
+    """The single-program mesh ladder (mesh × geometric) against the
+    uncompacted mesh loop (mesh × off), bit for bit.  A small bucket floor
+    gives these few-hundred-edge graphs several rungs."""
+    monkeypatch.setattr(api_mod, "_LADDER_MIN_EDGES", 64)
     edges, _ = gen.planted_dense_subgraph(
         n=400, avg_deg=4.0, k=40, p_dense=0.6, seed=seed
     )
     mesh = _mesh()
-    sh = shard_edges(edges, mesh, ("data",))
-    one = make_distributed_peel(mesh, ("data",), eps=eps, n_nodes=sh.n_nodes)
-    two = make_distributed_peel_twophase(
-        mesh, ("data",), eps=eps, n_nodes=sh.n_nodes, phase1_passes=k1
+    one = solve(
+        edges, Problem.undirected(eps=eps, substrate="mesh", compaction="off"),
+        mesh=mesh,
     )
-    r1 = one(sh.src, sh.dst, sh.weight, sh.mask)
-    r2 = two(sh.src, sh.dst, sh.weight, sh.mask)
-    assert float(r2.best_density) == pytest.approx(float(r1.best_density), rel=1e-6)
-    np.testing.assert_array_equal(
-        np.asarray(r1.best_alive), np.asarray(r2.best_alive)
+    lad = solve(
+        edges,
+        Problem.undirected(eps=eps, substrate="mesh", compaction="geometric"),
+        mesh=mesh,
     )
+    assert lad.extras["compaction"]["single_program"]
+    assert len(lad.extras["compaction"]["segments"]) > 1
+    for field in ("best_alive", "best_density", "best_size", "passes", "alive"):
+        a, b = np.asarray(getattr(one, field)), np.asarray(getattr(lad, field))
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), field
 
 
 def test_twophase_lemma4_bound_holds():
     """After k passes the alive count is below n/(1+eps)^k (the static size
     the compaction relies on)."""
-    from repro.core.peel import densest_subgraph
-
     edges = gen.chung_lu_power_law(n=5000, exponent=2.0, avg_deg=10.0, seed=3)
     eps = 0.5
-    res = densest_subgraph(edges, eps=eps, track_history=True)
+    res = solve(
+        edges, Problem.undirected(eps=eps, track_history=True, compaction="off")
+    )
     hn = np.asarray(res.history_n)[: int(res.passes)]
     for k in range(1, len(hn)):
         assert hn[k] <= edges.n_nodes / (1 + eps) ** k + 1e-9
@@ -55,25 +59,25 @@ def test_distributed_topk_meets_guarantee():
     """Distributed Algorithm 2: |S~| >= k and rho(S~) within (3+3eps) of the
     best-known >=k density (checked against exhaustive peel candidates)."""
     from repro.core.density import density_of
-    from repro.core.mapreduce import make_distributed_topk_peel
-    from repro.core.peel_topk import densest_subgraph_at_least_k
 
     eps, k = 0.5, 30
     edges, _ = gen.planted_dense_subgraph(
         n=300, avg_deg=4.0, k=25, p_dense=0.8, seed=7
     )
-    mesh = _mesh()
-    sh = shard_edges(edges, mesh, ("data",))
-    fn = make_distributed_topk_peel(
-        mesh, ("data",), k=k, eps=eps, n_nodes=sh.n_nodes
+    r = solve(
+        edges,
+        Problem.at_least_k(
+            k=k, eps=eps, substrate="mesh", min_deg_fallback=False,
+            ceil_count=True, compaction="off",
+        ),
+        mesh=_mesh(),
     )
-    r = fn(sh.src, sh.dst, sh.weight, sh.mask)
     n_sel = int(np.asarray(r.best_alive).sum())
     assert n_sel >= k
     # density of the returned set really is its density
-    assert float(density_of(sh, r.best_alive)) == pytest.approx(
+    assert float(density_of(edges, r.best_alive)) == pytest.approx(
         float(r.best_density), rel=1e-5
     )
     # agrees with the single-device Algorithm 2 within the approximation
-    ref = densest_subgraph_at_least_k(edges, k=k, eps=eps)
+    ref = solve(edges, Problem.at_least_k(k=k, eps=eps, compaction="off"))
     assert float(r.best_density) >= float(ref.best_density) / (3 * (1 + eps))
