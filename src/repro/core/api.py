@@ -26,7 +26,10 @@ Lowering map (Problem field -> engine axis)::
     objective  undirected   -> UndirectedThreshold(eps)           (Alg 1, §4.1)
                at_least_k   -> AtLeastKFraction(k, eps, variants) (Alg 2, §4.2)
                directed     -> DirectedST(eps, c)                 (Alg 3, §4.3)
-    backend    exact        -> ExactBackend (segment_sum)
+    backend    exact        -> ExactBackend (segment_sum), or SortedSrcBackend
+                               (src half off CSR offsets) on a src-sorted,
+                               unit-weight buffer of the jit ladder or an
+                               eps sweep
                sketch       -> SketchBackend / _MeshSketchBackend (§5.1)
                pallas       -> tiled-degree kernel via FnBackend  (kernels/)
     substrate  jit          -> jax.jit(run_peel)
@@ -39,6 +42,7 @@ Lowering map (Problem field -> engine axis)::
 from __future__ import annotations
 
 import collections
+import collections.abc
 import concurrent.futures
 import dataclasses
 import functools
@@ -62,6 +66,7 @@ from repro.core.engine import (
     MeshSegmentSumBackend,
     PeelOutcome,
     RemovalPolicy,
+    SortedSrcBackend,
     UndirectedThreshold,
     run_peel,
 )
@@ -709,11 +714,16 @@ def _backend_for(
     n_nodes: int,
     *,
     tiling: Optional[Tuple[jax.Array, jax.Array]] = None,
+    src_offsets: Optional[jax.Array] = None,
 ):
     """Problem -> DegreeBackend (jit substrate).  ``tiling`` carries the
     Pallas bucketing arrays as runtime values so compiled programs stay
-    graph-independent."""
+    graph-independent; so does ``src_offsets``, the CSR offsets of a
+    src-sorted unit-weight buffer (:func:`_src_offsets`), which turn the
+    exact backend's undirected count into :class:`SortedSrcBackend`'s."""
     if problem.backend == "exact":
+        if src_offsets is not None and problem.objective != "directed":
+            return SortedSrcBackend(src_offsets)
         return ExactBackend()
     if problem.backend == "sketch":
         from repro.core.countsketch import SketchBackend, make_sketch_params
@@ -748,6 +758,7 @@ def run_cell(
     eps: Any = None,
     c: Any = None,
     tiling: Optional[Tuple[jax.Array, jax.Array]] = None,
+    src_offsets: Optional[jax.Array] = None,
     max_passes: Optional[int] = None,
     init_alive: Optional[jax.Array] = None,
     init_t_alive: Optional[jax.Array] = None,
@@ -760,6 +771,8 @@ def run_cell(
     """The pure, traceable lowering core: one Problem cell -> ``run_peel``.
 
     Safe under jit/vmap/shard_map; ``eps`` and ``c`` may be traced scalars.
+    ``tiling`` and ``src_offsets`` are the backend's runtime arrays
+    (:func:`_backend_for`).
     Every jit program of solve()/solve_batch() bottoms out here.  The
     ``init_*``/``compact_below`` segment controls are forwarded to
     :func:`~repro.core.engine.run_peel` — ``run_cell`` itself is always ONE
@@ -769,7 +782,9 @@ def run_cell(
     prob = problem.resolve(edges.n_nodes)
     mp = max_passes if max_passes is not None else prob.resolved_max_passes(edges.n_nodes)
     policy = _policy_for(prob, eps=eps, c=c)
-    backend = _backend_for(prob, edges.n_nodes, tiling=tiling)
+    backend = _backend_for(
+        prob, edges.n_nodes, tiling=tiling, src_offsets=src_offsets
+    )
     return run_peel(
         edges, policy, backend, mp, track_history=prob.track_history,
         init_alive=init_alive, init_t_alive=init_t_alive, init_t=init_t,
@@ -887,6 +902,100 @@ def _compact_chunks(m_buf: int) -> int:
     return _COMPACT_WORKERS * constants.COMPACT_CHUNKS_PER_WORKER
 
 
+class _DegreeCountRoutes(collections.abc.Mapping):
+    """``extras["degree_count"]``, a read-only mapping: the passes whose
+    ``src`` half of the degree count was read off a prefix sum at CSR
+    offsets (``sorted_src_passes``, :class:`SortedSrcBackend`) and those
+    that scattered it (``scatter_src_passes``).  A batched result counts
+    the passes of every lane.  A value may be the solve's device pass
+    count: reading its key waits for the solve like any read of its
+    answer."""
+
+    KEYS = ("sorted_src_passes", "scatter_src_passes")
+
+    def __init__(self, sorted_passes: Any, scatter_passes: Any):
+        self._passes = dict(zip(self.KEYS, (sorted_passes, scatter_passes)))
+
+    def __getitem__(self, key: str) -> int:
+        return int(np.sum(self._passes[key]))
+
+    def __iter__(self):
+        return iter(self.KEYS)
+
+    def __len__(self) -> int:
+        return len(self.KEYS)
+
+
+def _src_reduce(sorted_src: bool) -> str:
+    """How a program counts the ``src`` half of the degrees, as
+    ``extras["compaction"]["segments"]`` names it."""
+    return "sorted" if sorted_src else "scatter"
+
+
+def _ladder_routes(ladder: Dict[str, Any]) -> _DegreeCountRoutes:
+    """``extras["degree_count"]`` of a ladder: its segments' passes, by
+    the route their program counts the ``src`` half by."""
+    passes = {r: 0 for r in ("sorted", "scatter")}
+    for seg in ladder["segments"]:
+        passes[seg["src_reduce"]] += seg["passes"]
+    return _DegreeCountRoutes(passes["sorted"], passes["scatter"])
+
+
+def _id_runs(src: np.ndarray, base: int, check: bool = False):
+    """Where each id's run starts in one chunk of a non-decreasing ``src``
+    that begins at slot ``base``: ``(ids, first slots)``, the chunk's first
+    slot always among them.  With ``check``, None if the chunk is not
+    non-decreasing (compaction keeps a sorted rung sorted, so its chunks
+    skip the check)."""
+    if len(src) == 0:
+        return src, np.zeros(0, np.int32)
+    if check and not (src[1:] >= src[:-1]).all():
+        return None
+    starts = np.flatnonzero(src[1:] != src[:-1]) + 1  # a bool scan, not an int one
+    return np.r_[src[:1], src[starts]], (np.r_[0, starts] + base).astype(np.int32)
+
+
+def _offsets_from_runs(runs, n_nodes: int, n_slots: int) -> np.ndarray:
+    """int32[n_nodes + 1] CSR offsets from the :func:`_id_runs` of the
+    consecutive chunks of a sorted ``src`` with ``n_slots`` real slots:
+    each node's first slot, or the next node's where it has none.  A run
+    that crosses a chunk boundary keeps its first chunk's start."""
+    off = np.full(n_nodes + 1, n_slots, np.int32)
+    for ids, first in reversed(runs):
+        off[ids] = first
+    return np.ascontiguousarray(np.minimum.accumulate(off[::-1])[::-1])
+
+
+def _src_offsets(
+    src: np.ndarray, w: np.ndarray, msk: np.ndarray, n_nodes: int
+) -> Optional[np.ndarray]:
+    """The CSR offsets :class:`SortedSrcBackend` reads for this host
+    buffer, or None where that count does not apply: it needs the real
+    slots (``msk``) first, their ``src`` non-decreasing inside
+    ``[0, n_nodes)``, and every real weight 1.0.  Chunks of
+    :func:`_compact_chunks` run on the compaction pool."""
+    k = int(np.count_nonzero(msk))
+    if not msk[:k].all():
+        return None
+    src, w = src[:k], w[:k]
+    if k and (src[0] < 0 or src[-1] >= n_nodes):
+        return None
+    chunks = _compact_chunks(k)
+    cuts = [k * i // chunks for i in range(chunks + 1)]
+    if any(src[c - 1] > src[c] for c in cuts[1:-1] if 0 < c < k):
+        return None
+
+    def sorted_run(i):
+        a, b = cuts[i], cuts[i + 1]
+        return _id_runs(src[a:b], a, check=True) if np.all(w[a:b] == 1) else None
+
+    run = map if chunks == 1 else _COMPACT_POOL.map
+    runs = list(run(sorted_run, range(chunks)))
+    if any(r is None for r in runs):
+        return None
+    return _offsets_from_runs(runs, n_nodes, k)
+
+
 class _Compacted(NamedTuple):
     """The next rung's host buffers, as :func:`_compact_rung` gathers them."""
 
@@ -899,6 +1008,7 @@ class _Compacted(NamedTuple):
     id_map: np.ndarray  # compact id -> original id
     e_alive: int
     n_alive: int
+    src_offsets: Optional[np.ndarray]  # asked for with ``offsets``
 
 
 def _at_floor(m: int, n: int, e_alive: int, n_alive: int, keep_ids: bool) -> bool:
@@ -911,7 +1021,8 @@ def _at_floor(m: int, n: int, e_alive: int, n_alive: int, keep_ids: bool) -> boo
 
 
 def _compact_rung(
-    src, dst, w, msk, s_al, t_al, id_map, chunks: int, keep_ids: bool = False
+    src, dst, w, msk, s_al, t_al, id_map, chunks: int, keep_ids: bool = False,
+    offsets: bool = False,
 ) -> Optional[_Compacted]:
     """Gather one rung's survivors into the next power-of-two bucket.
 
@@ -928,6 +1039,11 @@ def _compact_rung(
     buffers are the same for every ``chunks``.  More than one chunk runs on
     the pool (numpy's filters and gathers release the GIL); the node-sized
     steps stay serial.
+
+    With ``offsets`` (a ``src``-sorted rung: the order and the relabeling
+    keep it sorted) each chunk also notes where each new ``src`` id's run
+    starts as it gathers it (:func:`_id_runs`), and the result carries
+    the new buffer's CSR offsets (:func:`_src_offsets`).
 
     Returns None at the ladder's floor (:func:`_at_floor`): the rung then
     finishes uncompacted.
@@ -963,18 +1079,21 @@ def _compact_rung(
         out = slice(ends[i] - counts[i], ends[i])
         keep = np.flatnonzero(oks[i])
         if keep_ids:
-            new_src[out] = src[a:b][keep]
+            ids = src[a:b][keep]
             new_dst[out] = dst[a:b][keep]
         else:
-            new_src[out] = relabel[src[a:b][keep]]
+            ids = relabel[src[a:b][keep]]
             new_dst[out] = relabel[dst[a:b][keep]]
+        new_src[out] = ids
         new_w[out] = w[a:b][keep]
+        return _id_runs(ids, out.start) if offsets else None
 
-    for _ in run(gather, range(chunks)):
-        pass
+    runs = list(run(gather, range(chunks)))
     if keep_ids:
+        src_off = _offsets_from_runs(runs, len(s_al), e_alive) if offsets else None
         return _Compacted(
-            new_src, new_dst, new_w, new_msk, s_al, t_al, id_map, e_alive, n_alive
+            new_src, new_dst, new_w, new_msk, s_al, t_al, id_map, e_alive,
+            n_alive, src_off,
         )
     # id_map covers only the real (unpadded) ids; pad nodes are never
     # alive, so slicing the survivor mask to its length is exact.
@@ -986,8 +1105,10 @@ def _compact_rung(
     if t_al is not None:
         new_t = np.zeros(new_n, bool)
         new_t[:n_alive] = t_al[surv]
+    src_off = _offsets_from_runs(runs, new_n, e_alive) if offsets else None
     return _Compacted(
-        new_src, new_dst, new_w, new_msk, new_s, new_t, id_map, e_alive, n_alive
+        new_src, new_dst, new_w, new_msk, new_s, new_t, id_map, e_alive,
+        n_alive, src_off,
     )
 
 
@@ -1222,13 +1343,15 @@ class Solver:
         mp: int,
         kind: str,
         with_tiling: bool,
+        sorted_src: bool = False,
     ) -> Callable:
         solver = self
         directed = problem.objective == "directed"
 
-        def cell(edges, *, eps=None, c=None, tiling=None):
+        def cell(edges, *, eps=None, c=None, tiling=None, src_offsets=None):
             return run_cell(
-                edges, problem, eps=eps, c=c, tiling=tiling, max_passes=mp,
+                edges, problem, eps=eps, c=c, tiling=tiling,
+                src_offsets=src_offsets, max_passes=mp,
             )
 
         if kind == "solve":
@@ -1245,7 +1368,14 @@ class Solver:
                     solver._mark_trace()
                     return cell(edges)
         elif kind == "eps":
-            if with_tiling:
+            if sorted_src:
+                # One set of offsets, unbatched, for every lane.
+                def fn(edges, off, eps_vec):
+                    solver._mark_trace()
+                    return jax.vmap(
+                        lambda e: cell(edges, eps=e, src_offsets=off)
+                    )(eps_vec)
+            elif with_tiling:
                 def fn(edges, tl, ei, eps_vec):
                     solver._mark_trace()
                     return jax.vmap(
@@ -1273,9 +1403,11 @@ class Solver:
         seg_mp: int,
         compact_below: Optional[int],
         with_tiling: bool,
+        sorted_src: bool = False,
     ) -> Callable:
         """One rung of the compaction ladder on the jit substrate:
-        ``fn(edges[, tl, ei], alive0[, ta0], t0, ae0[, c]) -> PeelOutcome``.
+        ``fn(edges[, tl, ei | off], alive0[, ta0], t0, ae0[, c]) ->
+        PeelOutcome`` (``off``: the rung's CSR offsets, with ``sorted_src``).
         ``compact_below`` is baked in statically (it derives from the edge
         buffer size, which already keys the cache), so each power-of-two
         bucket compiles exactly once and is reused across graphs, segments
@@ -1286,9 +1418,10 @@ class Solver:
         solver = self
         directed = problem.objective == "directed"
 
-        def cell(edges, alive0, ta0, t0, ae0, c=None, tiling=None):
+        def cell(edges, alive0, ta0, t0, ae0, c=None, tiling=None, off=None):
             return run_cell(
-                edges, problem, c=c, tiling=tiling, max_passes=seg_mp,
+                edges, problem, c=c, tiling=tiling, src_offsets=off,
+                max_passes=seg_mp,
                 init_alive=alive0, init_t_alive=ta0, init_t=t0,
                 init_best_empty=True, compact_below=compact_below,
                 init_alive_edges=ae0, init_ok_from_mask=True,
@@ -1298,6 +1431,10 @@ class Solver:
             def fn(edges, tl, ei, alive0, t0, ae0):
                 solver._mark_trace()
                 return cell(edges, alive0, None, t0, ae0, tiling=(tl, ei))
+        elif sorted_src:
+            def fn(edges, off, alive0, t0, ae0):
+                solver._mark_trace()
+                return cell(edges, alive0, None, t0, ae0, off=off)
         elif directed:
             def fn(edges, alive0, ta0, t0, ae0, c):
                 solver._mark_trace()
@@ -1621,6 +1758,7 @@ class Solver:
                             else schedule[i + 1] * n_shards
                         ),
                         "cache_hit": bool(hit),
+                        "src_reduce": _src_reduce(False),
                     }
                 )
             ladder = {
@@ -1649,18 +1787,19 @@ class Solver:
         n_cur: int,
         m_cur: int,
         dtype,
-        tiling_shapes: Tuple,
+        aux_shapes: Tuple,
+        sorted_src: bool,
     ):
         """Cached program for one ladder rung."""
         with_tiling = prob.backend == "pallas"
         key = self._key(
             "cseg", prob, seg_mp, n_cur, m_cur, dtype,
-            (compact_below,) + tiling_shapes,
+            (compact_below, _src_reduce(sorted_src)) + aux_shapes,
         )
         return self._get(
             key,
             lambda: self._build_segment_program(
-                prob, seg_mp, compact_below, with_tiling
+                prob, seg_mp, compact_below, with_tiling, sorted_src
             ),
             disk_dir=self._disk_dir(prob),
         )
@@ -1703,6 +1842,13 @@ class Solver:
             dst = io.down(graph.dst)
             w = io.down(graph.weight)
             msk = io.down(graph.mask)
+            # Exact undirected rungs read the src half of the degree count
+            # off CSR offsets when the graph is src-sorted with unit
+            # weights; checked once here, and compaction keeps both.
+            src_off = None
+            if prob.backend == "exact" and not directed:
+                src_off = _src_offsets(src, w, msk, n0)
+        sorted_src = src_off is not None
         id_map = np.arange(n0, dtype=np.int64)  # compact id -> original id
         n_cur = n0
         s_al = np.ones(n0, bool)
@@ -1755,6 +1901,8 @@ class Solver:
                     # its tables on the device.
                     io.to_host += src.nbytes + dst.nbytes
                     io.to_device += sum(a.nbytes for a in aux_arrays)
+                elif sorted_src:
+                    aux_arrays = (io.up(src_off),)
                 # Carried segment state (must track the
                 # _build_segment_program signature): alive bitmap(s),
                 # absolute pass counter, entry alive-edge count, and the
@@ -1774,7 +1922,7 @@ class Solver:
             with span("densest.ladder.rung", **shape):
                 fn, hit = self._segment_fn(
                     prob, mp, compact_below, n_cur, m_buf, dtype,
-                    tuple(a.shape for a in aux_arrays),
+                    tuple(a.shape for a in aux_arrays), sorted_src,
                 )
                 out = fn(edges, *aux_arrays, *carried)
                 t_prev = t_done
@@ -1812,6 +1960,7 @@ class Solver:
                         "passes": int(seg_passes),
                         "compact_below": compact_below,
                         "cache_hit": bool(hit),
+                        "src_reduce": _src_reduce(sorted_src),
                     }
                 )
                 n_s = int(s_al.sum())
@@ -1824,7 +1973,8 @@ class Solver:
             chunks = _compact_chunks(len(src))
             with span("densest.ladder.compact", **shape, chunks=chunks):
                 nxt = _compact_rung(
-                    src, dst, w, msk, s_al, t_al, id_map, chunks, keep_ids
+                    src, dst, w, msk, s_al, t_al, id_map, chunks, keep_ids,
+                    offsets=sorted_src,
                 )
             if nxt is None:
                 # Bucket floor reached: finish on this buffer uncompacted.
@@ -1832,6 +1982,7 @@ class Solver:
                 continue
             segments[-1]["compact_chunks"] = chunks
             src, dst, w, msk, s_al, t_al, id_map = nxt[:7]
+            src_off = nxt.src_offsets
             n_cur = len(s_al)
             cur_alive_edges = nxt.e_alive
         else:
@@ -1912,11 +2063,12 @@ class Solver:
                 "c_density": np.asarray(rhos),
                 "c_passes": np.asarray(passes),
                 "compaction": best_ladder,
+                "degree_count": _ladder_routes(best_ladder),
             }
             return self._wrap(best, prob, n, mp, all_hit, extras=extras)
         c = prob.c if prob.objective == "directed" else None
         out, ladder, hit = runner(c)
-        extras = {"compaction": ladder}
+        extras = {"compaction": ladder, "degree_count": _ladder_routes(ladder)}
         if prob.backend == "sketch":
             from repro.core.countsketch import SketchCounters
 
@@ -1937,6 +2089,9 @@ class Solver:
         extras: Optional[Dict[str, Any]] = None,
         batch: Optional[str] = None,
     ) -> DenseSubgraphResult:
+        # Programs built without src offsets scatter both halves.
+        extras = dict(extras or {})
+        extras.setdefault("degree_count", _DegreeCountRoutes(0, out.passes))
         prov = Provenance(
             objective=problem.objective,
             policy=_policy_name(problem),
@@ -2352,17 +2507,35 @@ class Solver:
                 raise ValueError("eps sweeps over a directed Problem need a fixed c")
             with_tiling = prob.backend == "pallas"
             aux: Tuple = _tiling_arrays(graph, prob) if with_tiling else ()
+            # Exact undirected lanes read the src half of the degree count
+            # off CSR offsets of a src-sorted unit-weight graph, built once
+            # per call on the host and shared by every lane.
+            src_off = None
+            if prob.backend == "exact" and prob.objective != "directed":
+                src_off = _src_offsets(
+                    np.asarray(graph.src), np.asarray(graph.weight),
+                    np.asarray(graph.mask), n,
+                )
+            sorted_src = src_off is not None
+            if sorted_src:
+                aux = (jnp.asarray(src_off),)
             key = self._key(
                 "eps", prob, mp, n, graph.n_edges_padded,
-                graph.weight.dtype, tuple(a.shape for a in aux),
+                graph.weight.dtype,
+                (_src_reduce(sorted_src),) + tuple(a.shape for a in aux),
             )
             fn, hit = self._get(
                 key,
-                lambda: self._build_jit_program(prob, mp, "eps", with_tiling),
+                lambda: self._build_jit_program(
+                    prob, mp, "eps", with_tiling, sorted_src
+                ),
                 disk_dir=self._disk_dir(prob),
             )
             out = fn(graph, *aux, jnp.asarray(eps_host))
-            return self._wrap(out, prob, n, mp, hit, batch="eps")
+            extras = None
+            if sorted_src:
+                extras = {"degree_count": _DegreeCountRoutes(out.passes, 0)}
+            return self._wrap(out, prob, n, mp, hit, extras=extras, batch="eps")
 
         # c sweep (directed only)
         if prob.objective != "directed":

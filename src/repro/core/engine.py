@@ -50,6 +50,8 @@ Policy × backend matrix (the paper section each cell realizes)::
 
     —* = composes through the same engine but has no dedicated wrapper yet;
     any DegreeBackend works with any policy of matching directedness.
+    On a src-sorted unit-weight buffer the exact column's undirected count
+    reads its ``src`` half off CSR offsets (:class:`SortedSrcBackend`).
 
 The removal threshold ``2(1+eps)·rho(S)`` exists only here
 (:func:`removal_threshold`); wrappers must not re-derive it.
@@ -95,15 +97,76 @@ def removal_threshold(eps: float, rho: jax.Array) -> jax.Array:
     return 2.0 * (1.0 + eps) * rho
 
 
+class SortedSrc(NamedTuple):
+    """The ``src`` column of an edge buffer whose real slots come first
+    with ``src`` non-decreasing and unit weights, given by its CSR
+    offsets: node ``v`` owns slots ``[offsets[v], offsets[v + 1])`` and
+    ``offsets[n_nodes]`` is the real slot count.
+    :func:`segment_degree_count` takes it in place of the ids.  Like the
+    ids it has the buffer's ``shape``, so code that wraps the count and
+    sizes per-slot arrays from ``src`` (a fault injected into it, say)
+    still runs."""
+
+    offsets: jax.Array  # int32[n_nodes + 1]
+    n_slots: int  # the buffer's slot count, real and padding
+
+    @property
+    def shape(self) -> Tuple[int]:
+        return (self.n_slots,)
+
+
+_SCAN_BLOCK = 128
+
+
+def _slot_prefix(x: jax.Array, bound: int = 1) -> jax.Array:
+    """int32 sums of ``x[:j]`` at every slot ``j`` in ``0..len(x)`` (the
+    array may run past ``len(x) + 1``), for ``0 <= x <= bound``.
+
+    Blocks of 128 slots take their prefix as a matmul with a triangle of
+    ones, exact in float32 since every in-block sum stays below 2^24
+    (bfloat16 inputs while ``bound`` fits its 8 bits); the block sums
+    recurse.  Unlike ``jnp.cumsum``, whose reduce-window XLA's TPU
+    rewrite leaves without op names, every op here keeps the caller's
+    named scope."""
+    n = x.shape[0]
+    b = _SCAN_BLOCK
+    if n < b or bound * b >= 2**24:
+        return jnp.pad(jax.lax.associative_scan(jnp.add, x), (1, 0))
+    rows = n // b + 1  # covers slot n
+    xb = jnp.pad(x, (0, rows * b - n)).reshape(rows, b)
+    dt = jnp.bfloat16 if bound <= 256 else jnp.float32
+    tri = jnp.triu(jnp.ones((b, b), dt), 1)
+    within = jnp.matmul(
+        xb.astype(dt), tri, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    before = _slot_prefix(xb.sum(1), bound * b)[:rows]
+    return (within + before[:, None]).reshape(rows * b)
+
+
 def segment_degree_count(
-    src: jax.Array, dst: jax.Array, w_alive: jax.Array, n_nodes: int
+    src, dst: jax.Array, w_alive: jax.Array, n_nodes: int
 ) -> Tuple[jax.Array, jax.Array]:
     """The reduce-side degree count of §5.2: both-endpoint segment_sum plus
     the total alive edge weight.  The ONLY implementation of the exact
-    undirected count — :class:`ExactBackend`, ``density.exact_degrees`` and
-    the streaming chunk reducer all delegate here (like
-    :func:`removal_threshold`, the expression exists once)."""
-    deg = jax.ops.segment_sum(w_alive, src, num_segments=n_nodes)
+    undirected count — :class:`ExactBackend`, :class:`SortedSrcBackend`,
+    ``density.exact_degrees`` and the streaming chunk reducer all delegate
+    here (like :func:`removal_threshold`, the expression exists once).
+
+    ``src`` is the int32[E] ids, or a :class:`SortedSrc`: the ``src`` half
+    is then read off an int32 prefix sum of the alive slots at the CSR
+    offsets (:func:`_slot_prefix`) instead of scattered.  With unit
+    weights every count is an integer below 2^24, exact in float32, so
+    the two give the same bits."""
+    if isinstance(src, SortedSrc):
+        with jax.named_scope("sorted_src"):
+            cs = _slot_prefix((w_alive != 0).astype(jnp.int32))
+            at = cs.at[src.offsets].get(
+                indices_are_sorted=True, mode="promise_in_bounds"
+            )
+            deg = (at[1:] - at[:-1]).astype(jnp.float32)
+    else:
+        deg = jax.ops.segment_sum(w_alive, src, num_segments=n_nodes)
     deg = deg + jax.ops.segment_sum(w_alive, dst, num_segments=n_nodes)
     return deg, jnp.sum(w_alive)
 
@@ -363,6 +426,29 @@ class ExactBackend:
         out_deg = jax.ops.segment_sum(w_alive, edges.src, num_segments=n)
         in_deg = jax.ops.segment_sum(w_alive, edges.dst, num_segments=n)
         return out_deg, in_deg, jnp.sum(w_alive)
+
+
+class SortedSrcBackend:
+    """Exact undirected degrees for an edge buffer whose real slots come
+    first with ``src`` non-decreasing and unit weights: the ``src`` half
+    is a prefix sum read at the buffer's CSR ``src_offsets``
+    (:class:`SortedSrc`), the ``dst`` half a ``segment_sum``.
+    The caller checks the buffer and builds the offsets once per buffer
+    (``core/api.py``); :class:`ExactBackend`, which the mesh inherits,
+    keeps both scatters."""
+
+    def __init__(self, src_offsets: jax.Array):
+        self.src_offsets = src_offsets
+
+    def undirected(self, edges, w_alive):
+        src = SortedSrc(self.src_offsets, edges.n_edges_padded)
+        return segment_degree_count(src, edges.dst, w_alive, edges.n_nodes)
+
+    def directed(self, edges, w_alive):
+        raise NotImplementedError(
+            "SortedSrcBackend counts undirected degrees; the directed rule "
+            "keeps ExactBackend's two scatters"
+        )
 
 
 class FnBackend:

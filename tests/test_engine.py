@@ -30,8 +30,11 @@ from repro.core.engine import (
     ExactBackend,
     FnBackend,
     MeshSegmentSumBackend,
+    SortedSrc,
+    SortedSrcBackend,
     UndirectedThreshold,
     run_peel,
+    segment_degree_count,
     undirected_pass_step,
 )
 from repro.graph import from_numpy
@@ -257,6 +260,83 @@ def test_matrix_directed_st(backend, c):
     np.testing.assert_array_equal(np.asarray(res.best_t), ref_t)
     assert float(res.best_density) == pytest.approx(ref_rho, rel=1e-6)
     assert int(res.passes) == ref_passes
+
+
+# ---------------------------------------------------------------------------
+# Sorted-source degree count: bit-equal to the scatter on src-sorted buffers
+# ---------------------------------------------------------------------------
+
+
+# Slot counts that reach each level of engine._slot_prefix: under one
+# 128-slot block, a whole number of blocks, past 128^2 slots (a second
+# bfloat16 level) and past 128^3 (the float32 level).
+SORTED_SLOTS = {"one_block": 100, "block_multiple": 512, "two_levels": 40_000,
+                "float32_level": 2_200_000}
+
+
+def _sorted_buffer(case):
+    """(src, dst, mask, weight, n_nodes, alive[lanes, n]) of a src-sorted
+    unit-weight buffer: real slots first, ids drawn below ``n_real``."""
+    rng = np.random.default_rng(11)
+    n_real, m = 60, SORTED_SLOTS.get(case, 500)
+    n = 64 if case == "pad_nodes" else n_real
+    # Nodes 7..12 and the last three own no src slot.
+    ids = np.r_[np.arange(7), np.arange(13, n_real - 3)]
+    src = np.sort(rng.choice(ids, m)).astype(np.int32)
+    dst = rng.integers(0, n_real, m).astype(np.int32)
+    mask = np.ones(m, bool)
+    if case == "tail_padding":
+        src, dst = np.r_[src, np.zeros(37, np.int32)], np.r_[dst, np.zeros(37, np.int32)]
+        mask = np.r_[mask, np.zeros(37, bool)]
+    weight = np.ones(len(src), np.float32)
+    lanes = 3 if case == "lanes" else 1
+    alive = rng.random((lanes, n)) < 0.7
+    alive[:, n_real:] = False  # pad nodes are never alive
+    if case == "all_dead":
+        alive[:] = False
+    return src, dst, mask, weight, n, alive
+
+
+@pytest.mark.parametrize(
+    "case", ["tail_padding", "empty_segments", "pad_nodes", "all_dead", "lanes",
+             *SORTED_SLOTS]
+)
+def test_sorted_src_count_is_bit_equal_to_segment_sum(case):
+    src, dst, mask, weight, n, alive = _sorted_buffer(case)
+    off = np.searchsorted(src[: mask.sum()], np.arange(n + 1)).astype(np.int32)
+
+    def both(alive):
+        ok = mask & alive[src] & alive[dst]
+        w_alive = jnp.where(ok, weight, 0.0)
+        return (
+            segment_degree_count(src, dst, w_alive, n),
+            segment_degree_count(SortedSrc(off, len(src)), dst, w_alive, n),
+        )
+
+    (deg, total), (deg_s, total_s) = jax.jit(jax.vmap(both))(alive)
+    assert deg.shape == (alive.shape[0], n)
+    assert np.asarray(deg).tobytes() == np.asarray(deg_s).tobytes()
+    assert np.asarray(total).tobytes() == np.asarray(total_s).tobytes()
+    if case == "all_dead":
+        assert not np.asarray(deg_s).any()
+
+
+@pytest.mark.parametrize("policy", ["undirected", "at_least_k"])
+def test_sorted_src_backend_peels_as_exact(policy):
+    edges = dict(GRAPHS)["planted"]()  # dedup_edges leaves src sorted
+    src = np.asarray(edges.src)
+    assert np.all(np.diff(src) >= 0)
+    off = jnp.asarray(np.searchsorted(src, np.arange(edges.n_nodes + 1)), jnp.int32)
+    pol = UndirectedThreshold(0.1) if policy == "undirected" else AtLeastKFraction(k=30, eps=0.5)
+
+    def run(backend):
+        return jax.jit(lambda e: run_peel(e, pol, backend, 64, track_history=True))(edges)
+
+    a, b = run(ExactBackend()), run(SortedSrcBackend(off))
+    for x, y in zip(a, b):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+    with pytest.raises(NotImplementedError):
+        SortedSrcBackend(off).directed(edges, edges.weight)
 
 
 # ---------------------------------------------------------------------------

@@ -130,7 +130,8 @@ def test_chunked_compaction_opens_no_span_off_the_solving_thread(
 def test_ladder_counts_the_bytes_it_moves():
     """``bytes_to_host``/``bytes_to_device`` against the copies worked out
     by hand from the rungs: the graph comes down once; each rung goes up
-    with its bitmap and two int32 scalars, and comes back as its pass count,
+    with its bitmap, two int32 scalars and (the graph is src-sorted with
+    unit weights) its int32 CSR offsets, and comes back as its pass count,
     final bitmap, best density and history, plus its best set and size
     when it improves on the best so far; the answer goes up at the end."""
     g = _graph()
@@ -146,7 +147,9 @@ def test_ladder_counts_the_bytes_it_moves():
     to_device = 0
     best, t = -np.inf, 0
     for seg in lad["segments"]:
-        to_device += seg["m_buf"] * edge_bytes + seg["n_buf"] + 4 + 4
+        assert seg["src_reduce"] == "sorted"
+        offsets = 4 * (seg["n_buf"] + 1)
+        to_device += seg["m_buf"] * edge_bytes + seg["n_buf"] + 4 + 4 + offsets
         to_host += 4 + seg["n_buf"] + 4 + hist
         seg_best = rho[t: t + seg["passes"]].max(initial=-np.inf)
         t += seg["passes"]
@@ -171,13 +174,17 @@ def test_a_graph_already_on_the_host_is_not_counted_again():
     assert again["bytes_to_device"] == first["bytes_to_device"]
 
 
-def _lowered_peel(track_history=False):
+def _lowered_peel(track_history=False, sorted_src=False):
     g = _graph()
     policy = engine.UndirectedThreshold(0.5)
+    backend = engine.ExactBackend()
+    if sorted_src:
+        off = np.searchsorted(np.asarray(g.src), np.arange(g.n_nodes + 1))
+        backend = engine.SortedSrcBackend(jnp.asarray(off, jnp.int32))
 
     def fn(edges):
         return engine.run_peel(
-            edges, policy, engine.ExactBackend(), 32,
+            edges, policy, backend, 32,
             compact_below=64, track_history=track_history,
         )
 
@@ -188,6 +195,22 @@ def test_lowered_pass_body_carries_every_phase_scope():
     text = _lowered_peel().as_text(debug_info=True)
     for phase in PHASES:
         assert re.search(rf"peel/[^\"]*\b{phase}/", text), phase
+
+
+def test_sorted_src_ops_sit_in_the_degree_count_scope():
+    """The prefix sum and its offset reads are named
+    ``peel/.../degree_count/sorted_src``, so ``pass.degree_count_s``
+    charges them."""
+    def op_names(**kw):
+        hlo = _lowered_peel(**kw).compile().as_text()
+        return re.findall(r'op_name="([^"]*/sorted_src/[^"]*)"', hlo)
+
+    names = op_names(sorted_src=True)
+    assert any("dot_general" in n for n in names), names
+    assert any("gather" in n for n in names), names
+    assert all(re.match(r"[^/]*/peel/[^\"]*\bdegree_count/sorted_src/", n)
+               for n in names), names
+    assert not op_names()
 
 
 def test_named_scopes_leave_the_compiled_program_unchanged(monkeypatch):

@@ -1,4 +1,5 @@
-"""Compile-only checks of the main-path Pallas kernels for a TPU v5e chip.
+"""Compile-only checks of the main-path Pallas kernels (and of the op names
+the trace readers rely on) for a TPU v5e chip.
 
 The TPU compiler ships with jaxlib and compiles for a chip that is only
 described, so these tests need no accelerator: they catch what interpret
@@ -8,6 +9,8 @@ inside a module-scoped fixture (never at import time), so every test worker
 collects the same tests and only the worker that runs this file loads the
 TPU compiler.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,3 +104,29 @@ def test_count_sketch_compiles_for_tpu(one_chip):
         *(s((t,), jnp.uint32) for _ in range(4)),
     )
     assert "tpu_custom_call" in text
+
+
+def test_sorted_src_count_keeps_its_scope_on_tpu(one_chip):
+    """Every op of the sorted-source degree count, as the TPU compiler
+    leaves it, carries the ``degree_count`` scope, so the device trace
+    charges it to ``pass.degree_count_s``.  (``jnp.cumsum`` fails this:
+    XLA's TPU rewrite of its reduce-window emits ops with no name.)  The
+    slot count reaches the float32 level of the block prefix."""
+    from repro.core.engine import SortedSrc, segment_degree_count
+
+    n, m = 200_000, 2_200_000
+
+    def count(off, dst, w):
+        with jax.named_scope("degree_count"):
+            return segment_degree_count(SortedSrc(off, m), dst, w, n)
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = _compiled_text(
+        count, s((n + 1,), jnp.int32), s((m,), jnp.int32), s((m,), jnp.float32)
+    )
+    ops = [line for line in text[text.index("\nENTRY"):].splitlines()
+           if "estimated_cycles" in line]
+    assert any("sorted_src/" in op for op in ops)
+    unnamed = [op.strip()[:100] for op in ops
+               if not re.search(r'op_name="[^"]*degree_count/', op)]
+    assert not unnamed, unnamed
